@@ -114,6 +114,7 @@ print("CHAOS_SHARD " + json.dumps({
     "dropped_queries": int(st_dead.get("dropped_queries", 0)),
     "degraded_shards": list(st_dead.get("degraded_shards", [])),
     "cover_frac": float(st_dead.get("cover_frac", 0.0)),
+    "platform": jax.devices()[0].platform,
 }))
 """
 
@@ -125,7 +126,7 @@ def _shard_phase(n_devices: int = 4, timeout: int = 600) -> dict:
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n_devices}").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"     # the forced CPU topology, always
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(_REPO, "src"), env.get("PYTHONPATH")) if p)
     proc = subprocess.run([sys.executable, "-c", _SHARD_CHILD_SRC],
@@ -304,6 +305,7 @@ def run_smoke(n0: int = 256, dk: int = 16, vocab: int = 32,
              degraded_recall_full=round(
                  shard.get("degraded_recall_full", 0.0), 4),
              cover_frac=round(shard.get("cover_frac", 0.0), 4),
+             shard_phase_platform=shard.get("platform"),
              degraded_shards=shard.get("degraded_shards", []),
              fallback_bitident=fallback_bitident,
              fallback_step=fallback_step, torn_step=torn_step,

@@ -3,12 +3,14 @@ zero-rebuild cold start (the CI receipt for core/persist.py).
 
 Modes (``python benchmarks/bench_persist.py --mode ...``):
 
-  * ``smoke`` (default) — the gated CI lane: builds a small datastore
-    with the full serving state attached (int8 mirror + router), runs a
-    streamed insert + delete so the snapshot carries tombstones and
-    post-build rows, snapshots it, then restores IN A FRESH PROCESS
-    (subprocess — nothing cached, the honest cold start) and answers the
-    same query batch on both sides. Emits ``results/bench/persist.json``
+  * ``smoke`` (default) — the gated CI lane: a build child builds a small
+    datastore with the full serving state attached (int8 mirror +
+    router), runs a streamed insert + delete so the snapshot carries
+    tombstones and post-build rows, snapshots it and answers a query
+    batch; then a restore child restores IN A FRESH PROCESS (nothing
+    cached, the honest cold start) and answers the same batch. The
+    parent never touches JAX — on a TPU host only one process can hold
+    the chip, so each child holds it in turn. Emits ``results/bench/persist.json``
     with ``ids_bitident`` / ``dists_bitident`` (restored search results
     compared to the live store's, float bits and all), ``rebuild_s``
     (what a restart pays without persistence: the full NN-Descent build
@@ -18,10 +20,12 @@ Modes (``python benchmarks/bench_persist.py --mode ...``):
     informative ``smoke_persist_qfirst`` row measures the quantized-first
     cold start (serve from the int8 mirror while fp32 loads) — not gated.
 
-  * ``restore-child`` — internal: the fresh-process half of the smoke
-    lane. Restores from ``--dir``, regenerates the (deterministic,
-    seeded) query batch, searches, and prints one ``RESTORE_RESULT``
-    JSON line for the parent to compare bit-for-bit.
+  * ``build-child`` / ``restore-child`` — internal: the two halves of
+    the smoke lane. The build child builds and snapshots into ``--dir``;
+    the restore child restores from it. Each regenerates the
+    (deterministic, seeded) query batch, searches, and prints one
+    ``BUILD_RESULT`` / ``RESTORE_RESULT`` JSON line for the parent to
+    compare bit-for-bit.
 
 The snapshot directory lands under results/bench/persist_smoke/ so the
 CI artifact picks up its manifest.json next to the bench JSON.
@@ -36,8 +40,6 @@ import subprocess
 import sys
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -46,6 +48,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _queries(n: int, d: int, q_n: int):
     """Deterministic query batch derived from the (seeded) smoke corpus —
     regenerated identically on both sides of the process boundary."""
+    import jax
     from repro.core import datasets
     x = datasets.clustered(jax.random.key(20), n, d, 16)
     q = x[:q_n] + 0.01 * jax.random.normal(jax.random.key(23), (q_n, d))
@@ -53,6 +56,7 @@ def _queries(n: int, d: int, q_n: int):
 
 
 def _search(ds, q, k_out: int):
+    import jax
     dist, idx = ds.store.search(q, k_out=k_out, key=jax.random.key(24))
     return (np.asarray(dist, np.float32).view(np.int32),
             np.asarray(idx, np.int32))
@@ -62,6 +66,8 @@ def _build_live(n: int, d: int, k: int):
     """Full build (the cost persistence avoids) + post-build mutations
     (so the snapshot carries tombstones, streamed rows, and the
     incrementally-maintained mirror/router — the real online state)."""
+    import jax
+    import jax.numpy as jnp
     from repro.core.nn_descent import DescentConfig
     from repro.core.router import RouterConfig
     from repro.serve.knn_lm import MutableKNNDatastore
@@ -94,6 +100,7 @@ def run_restore_child(snap_dir: str, n: int, d: int, q_n: int,
                       k_out: int, qfirst: bool) -> None:
     """Fresh-process restore: nothing from the builder process survives
     except the snapshot directory. Prints RESTORE_RESULT for the parent."""
+    import jax
     from repro.serve.knn_lm import MutableKNNDatastore
     t0 = time.perf_counter()
     ds = MutableKNNDatastore.restore(snap_dir)
@@ -129,6 +136,43 @@ def run_restore_child(snap_dir: str, n: int, d: int, q_n: int,
     print("RESTORE_RESULT " + json.dumps(out), flush=True)
 
 
+def run_build_child(snap_root: str, n: int, d: int, q_n: int, k: int,
+                    k_out: int) -> None:
+    """Build + mutate + snapshot into ``snap_root``, answer the query
+    batch on the live store. Prints BUILD_RESULT for the parent."""
+    ds, rebuild_s = _build_live(n, d, k)
+    step_dir = ds.snapshot(snap_root, keep=1)
+    _, q = _queries(n, d, q_n)
+    bits, ids = _search(ds, q, k_out)
+    print("BUILD_RESULT " + json.dumps({
+        "rebuild_s": rebuild_s,
+        "snapshot_mb": _dir_mb(step_dir),
+        "ids": ids.ravel().tolist(),
+        "dist_bits": bits.ravel().tolist(),
+    }), flush=True)
+
+
+def _run_child(mode: str, snap_root: str, tag: str, args: list) -> dict:
+    """Run one half of the smoke lane in its own process; returns the
+    JSON it printed after ``tag``."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(_REPO, "src"), _REPO,
+                    env.get("PYTHONPATH")) if p)
+    cmd = [sys.executable, os.path.abspath(__file__), "--mode", mode,
+           "--dir", snap_root] + args
+    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
+                          cwd=_REPO, timeout=900)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"{mode} failed (rc={proc.returncode}):\n{proc.stderr}")
+    lines = [ln for ln in proc.stdout.splitlines()
+             if ln.startswith(tag + " ")]
+    if not lines:
+        raise RuntimeError(f"{mode} printed no {tag}:\n{proc.stdout}")
+    return json.loads(lines[-1][len(tag) + 1:])
+
+
 def run_smoke(n: int = 4096, d: int = 16, q_n: int = 256, k: int = 10,
               k_out: int = 10, qfirst: bool = True) -> list:
     from benchmarks.common import RESULTS_DIR, Sink
@@ -139,33 +183,16 @@ def run_smoke(n: int = 4096, d: int = 16, q_n: int = 256, k: int = 10,
     # lane must only ever see the snapshot written by THIS run
     shutil.rmtree(snap_root, ignore_errors=True)
 
-    ds, rebuild_s = _build_live(n, d, k)
-    step_dir = ds.snapshot(snap_root, keep=1)
-    snapshot_mb = _dir_mb(step_dir)
-    _, q = _queries(n, d, q_n)
-    bits_live, ids_live = _search(ds, q, k_out)
-
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(_REPO, "src"), _REPO,
-                    env.get("PYTHONPATH")) if p)
-    cmd = [sys.executable, os.path.abspath(__file__),
-           "--mode", "restore-child", "--dir", snap_root,
-           "--n", str(n), "--d", str(d), "--q", str(q_n),
-           "--k-out", str(k_out)]
-    if qfirst:
-        cmd.append("--qfirst")
-    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                          cwd=_REPO, timeout=900)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"restore child failed (rc={proc.returncode}):\n{proc.stderr}")
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("RESTORE_RESULT ")]
-    if not lines:
-        raise RuntimeError(
-            f"restore child printed no RESTORE_RESULT:\n{proc.stdout}")
-    res = json.loads(lines[-1][len("RESTORE_RESULT "):])
+    sizes = ["--n", str(n), "--d", str(d), "--q", str(q_n),
+             "--k-out", str(k_out)]
+    built = _run_child("build-child", snap_root, "BUILD_RESULT",
+                       sizes + ["--k", str(k)])
+    res = _run_child("restore-child", snap_root, "RESTORE_RESULT",
+                     sizes + (["--qfirst"] if qfirst else []))
+    ids_live = np.asarray(built["ids"], np.int32).reshape(q_n, k_out)
+    bits_live = np.asarray(built["dist_bits"], np.int32).reshape(q_n, k_out)
+    rebuild_s = float(built["rebuild_s"])
+    snapshot_mb = float(built["snapshot_mb"])
 
     ids_child = np.asarray(res["ids"], np.int32).reshape(ids_live.shape)
     bits_child = np.asarray(res["dist_bits"],
@@ -193,24 +220,30 @@ def run_smoke(n: int = 4096, d: int = 16, q_n: int = 256, k: int = 10,
 
 def main(argv: list | None = None):
     p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument("--mode", choices=("smoke", "restore-child"),
+    p.add_argument("--mode", choices=("smoke", "build-child",
+                                      "restore-child"),
                    default="smoke")
     p.add_argument("--dir", default=None,
-                   help="snapshot directory (restore-child mode)")
+                   help="snapshot directory (child modes)")
     p.add_argument("--n", type=int, default=4096)
     p.add_argument("--d", type=int, default=16)
     p.add_argument("--q", type=int, default=256)
+    p.add_argument("--k", type=int, default=10)
     p.add_argument("--k-out", type=int, default=10)
     p.add_argument("--qfirst", action="store_true", default=None,
                    help="also measure the quantized-first cold start "
                         "(informative row; on by default in smoke mode)")
     args = p.parse_args(argv)
+    if args.mode != "smoke" and args.dir is None:
+        p.error(f"--mode {args.mode} requires --dir")
+    if args.mode == "build-child":
+        return run_build_child(args.dir, args.n, args.d, args.q, args.k,
+                               args.k_out)
     if args.mode == "restore-child":
-        if args.dir is None:
-            p.error("--mode restore-child requires --dir")
         return run_restore_child(args.dir, args.n, args.d, args.q,
                                  args.k_out, bool(args.qfirst))
-    return run_smoke(n=args.n, d=args.d, q_n=args.q, k_out=args.k_out,
+    return run_smoke(n=args.n, d=args.d, q_n=args.q, k=args.k,
+                     k_out=args.k_out,
                      qfirst=True if args.qfirst is None else args.qfirst)
 
 

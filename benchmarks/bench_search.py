@@ -517,9 +517,10 @@ scfg = SearchConfig(beam=16, rounds=24, expand=4)
 _, _, st = graph_search_sharded(mesh, x, gidx, q, k_out=10, cfg=scfg,
                                 key=jax.random.key(2), router=router,
                                 route_p=1, route_cap=48, with_stats=True)
-print("ROUTED_STATS " + json.dumps(
-    {k: (v if isinstance(v, (list, tuple, float)) else int(v))
-     for k, v in st.items()}))
+st = {k: (v if isinstance(v, (list, tuple, float)) else int(v))
+      for k, v in st.items()}
+st["platform"] = jax.devices()[0].platform
+print("ROUTED_STATS " + json.dumps(st))
 """
 
 
@@ -532,7 +533,7 @@ def _routed_dispatch_stats(n_devices: int = 4, timeout: int = 600) -> dict:
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n_devices}").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"     # the forced CPU topology, always
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(repo, "src"), env.get("PYTHONPATH")) if p)
     proc = subprocess.run([sys.executable, "-c", _ROUTED_STATS_SRC],
@@ -592,6 +593,7 @@ def run_smoke_router(n: int = 4096, d: int = 16, n_clusters: int = 32,
              rounds=rounds, expand=expand,
              n_clusters=n_clusters,
              n_centroids=router.centroids.shape[0],
+             route_stats_platform=st["platform"],
              route_fanout=st["fanout"],
              route_shards=st["shards"],
              routed_queries=st["routed_queries"],

@@ -5,13 +5,12 @@ import json
 import os
 import time
 
-import jax
-
 RESULTS_DIR = os.environ.get("BENCH_RESULTS", "results/bench")
 
 
 def timeit(fn, *args, warmup: int = 1, iters: int = 3) -> float:
     """Median wall time of fn(*args) with block_until_ready."""
+    import jax
     for _ in range(warmup):
         jax.block_until_ready(fn(*args))
     ts = []

@@ -40,21 +40,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import faults, heap, selection
+from repro.core import metric as metric_mod
 from repro.core.graph_search import SearchConfig, graph_search
-
-# jax.shard_map landed in 0.5; fall back to the experimental module on
-# 0.4.x (same semantics — check_vma was called check_rep there)
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _xp_shard_map
-
-    def shard_map(f=None, /, **kw):
-        kw["check_rep"] = kw.pop("check_vma", False)
-        if f is None:
-            return functools.partial(_xp_shard_map, **kw)
-        return _xp_shard_map(f, **kw)
 from repro.core.heap import NeighborLists
+from repro.core.layout import chunked_rows, flatten_rows
 from repro.core.nn_descent import DescentConfig, invert_candidates, pair_block
 from repro.kernels import ops
 
@@ -103,7 +92,7 @@ def exact_knn_sharded(mesh: Mesh, x: jax.Array, k: int, *, axis: str = "data"):
         )
         return nl_d, nl_i
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=P(axis, None),
@@ -155,20 +144,13 @@ def fetch_rows_a2a(x_local, ids, *, axis: str, P_: int, n_local: int,
 
     Returns (rows (m, d), ok (m,) bool).
     """
-    m = ids.shape[0]
-    d = x_local.shape[1]
     p = jax.lax.axis_index(axis)
     base = p * n_local
     valid = ids >= 0
-    dest = jnp.clip(ids // n_local, 0, P_ - 1)
-    dest_k = jnp.where(valid, dest, P_)
-    order = jnp.argsort(dest_k)
-    dest_s = dest_k[order]
-    ids_s = ids[order]
-    first = jnp.searchsorted(dest_s, jnp.arange(P_ + 1), side="left")
-    pos = jnp.arange(m) - first[jnp.clip(dest_s, 0, P_)]
+    dest = jnp.where(valid, jnp.clip(ids // n_local, 0, P_ - 1), P_)
+    pos = _bucket_slots(dest, P_)
     req = jnp.full((P_, cap), -1, jnp.int32)
-    req = req.at[dest_s, pos].set(ids_s, mode="drop")
+    req = req.at[dest, pos].set(ids, mode="drop")
 
     got = jax.lax.all_to_all(req[:, None, :], axis, split_axis=0,
                              concat_axis=0, tiled=False)[:, 0, :]
@@ -181,12 +163,21 @@ def fetch_rows_a2a(x_local, ids, *, axis: str, P_: int, n_local: int,
     back = jax.lax.all_to_all(rows[:, None], axis, split_axis=0,
                               concat_axis=0, tiled=False)[:, 0]
 
-    in_bucket = (dest_s < P_) & (pos >= 0) & (pos < cap)
-    fetched = back[jnp.clip(dest_s, 0, P_ - 1), jnp.clip(pos, 0, cap - 1)]
-    out = jnp.zeros((m, d), x_local.dtype)
-    out = out.at[order].set(jnp.where(in_bucket[:, None], fetched, zero))
-    ok = jnp.zeros((m,), bool).at[order].set(in_bucket)
-    return out, ok & (ids >= 0)
+    ok = valid & (pos < cap)
+    fetched = back[jnp.clip(dest, 0, P_ - 1), jnp.clip(pos, 0, cap - 1)]
+    return jnp.where(ok[:, None], fetched, zero), ok
+
+
+def _bucket_slots(dest: jax.Array, P_: int):
+    """Each row's slot in its destination's bucket: its rank among the
+    rows bound for the same shard, in row order (``dest == P_``: no
+    destination, slot past every bucket). Prefix sums over the P
+    destinations, where a sort by destination compiled for tens of
+    seconds on TPU."""
+    hit = dest[None, :] == jnp.arange(P_, dtype=dest.dtype)[:, None]
+    rank = jnp.cumsum(hit.astype(jnp.int32), axis=1) - 1          # (P, m)
+    pos = jnp.sum(jnp.where(hit, rank, 0), axis=0)
+    return jnp.where(dest < P_, pos, dest.shape[0])
 
 
 def nn_descent_sharded_iteration(
@@ -216,50 +207,44 @@ def nn_descent_sharded_iteration(
     )
     cand = jnp.concatenate([cand[:half], base + cand[half:]])
 
-    # turbosampling accept (reverse degree approximated by local counts
-    # all-reduced — global degree of each node needs its incidences which
-    # are distributed; we segment-sum into the owner's (n_local,) slice)
+    # turbosampling accept, per pool as in selection.selection_turbo:
+    # a local receiver's new/old degrees are counted over the incidences
+    # this shard generated for it (its forward slots and the reverse
+    # incidences of local rows — all of them on cluster-aligned shards);
+    # a remote receiver's degree is not known here, so its incidences
+    # accept at rho_k / 2k
     owner_rows = recv - base
-    deg_new_local = jax.ops.segment_sum(
-        (valid & is_new).astype(jnp.int32),
-        jnp.where((owner_rows >= 0) & (owner_rows < n_local), owner_rows, n_local),
-        num_segments=n_local + 1,
-    )[:n_local]
-    # remote-receiver incidences counted on their owner via psum of bincount
-    # over the global id space is O(n) — instead each shard uses k (forward
-    # degree) + its local reverse count as the |N| estimate. Exact global
-    # degree costs one extra all_to_all; the estimate only perturbs the
-    # accept probability (sampling stays unbiased per pool).
-    deg_new = k + deg_new_local
+    in_local = (owner_rows >= 0) & (owner_rows < n_local)
+    seg = jnp.where(in_local, owner_rows, n_local)
+
+    def p_edge(mask):
+        deg = jax.ops.segment_sum(mask.astype(jnp.int32), seg,
+                                  num_segments=n_local + 1)[:n_local]
+        p_local = jnp.minimum(1.0, cfg.rho_k / jnp.maximum(deg, 1))
+        return jnp.where(in_local, p_local[jnp.clip(owner_rows, 0,
+                                                    n_local - 1)],
+                         cfg.rho_k / (2.0 * k))
+
     k_acc, k_rnd, key = jax.random.split(key, 3)
-    p_new = jnp.minimum(1.0, cfg.rho_k / jnp.maximum(deg_new, 1))
     u = jax.random.uniform(k_acc, recv.shape)
-    p_edge = p_new[jnp.clip(owner_rows, 0, n_local - 1)]
-    p_edge = jnp.where(
-        (owner_rows >= 0) & (owner_rows < n_local), p_edge, cfg.rho_k / (2.0 * k)
-    )
-    acc_new = valid & is_new & (u < p_edge)
-    acc_old = valid & ~is_new & (u < p_edge)
+    acc_new = valid & is_new & (u < p_edge(valid & is_new))
+    acc_old = valid & ~is_new & (u < p_edge(valid & ~is_new))
 
-    # route accepted incidences to receiver owners (fixed buckets)
-    cap = max(2 * cfg.rho_k * max(n_local // max(P_, 1), 1), 8)
-    def route(acc_mask, subkey):
-        payload = jnp.stack([recv, cand], axis=1)
+    # route accepted incidences to receiver owners
+    def route(acc_mask):
         return _all_to_all_route(
-            payload, acc_mask, recv // n_local, P_, cap, axis, subkey
-        )
+            (recv, cand), acc_mask, recv // n_local, P_, axis)
 
-    k_r1, k_r2, key = jax.random.split(key, 3)
-    got_new = route(acc_new, k_r1)        # (P_*cap, 2) rows targeting me
-    got_old = route(acc_old, k_r2)
+    got_new = route(acc_new)        # 2 x (P_*m,) rows targeting me
+    got_old = route(acc_old)
 
     def compact(got, c):
-        r = got[:, 0]
+        r, got_c = got
         valid_r = r >= 0
         rl = jnp.where(valid_r, r - base, -1)
         rnd = jax.random.uniform(jax.random.fold_in(key, c), r.shape)
         from repro.core.selection import _compact
-        return _compact(rl, got[:, 1], valid_r, rnd, n_local, c)
+        return _compact(rl, got_c, valid_r, rnd, n_local, c)
 
     cand_new = compact(got_new, cfg.rho_k)
     cand_old = compact(got_old, cfg.rho_k)
@@ -272,9 +257,9 @@ def nn_descent_sharded_iteration(
 
     # ---- fetch candidate features, evaluate pair distances
     cn, co = cand_new, cand_old
-    flat = jnp.concatenate([cn.reshape(-1), co.reshape(-1)])
+    flat = jnp.concatenate([flatten_rows(cn), flatten_rows(co)])
     if fetch == "a2a":
-        cap_f = max(2 * flat.shape[0] // max(P_, 1), 16)
+        cap_f = flat.shape[0]
         feats, fok = fetch_rows_a2a(
             x_local, flat, axis=axis, P_=P_, n_local=n_local, cap=cap_f)
         # candidates whose fetch overflowed the bucket: invalidate
@@ -309,24 +294,21 @@ def nn_descent_sharded_iteration(
         & jnp.broadcast_to(vo[:, None, :], (n_local, cn_b, co_b)).reshape(n_local, -1)
         & (a_no != b_no)
     )
-    a = jnp.concatenate([a_nn, b_nn, a_no, b_no], axis=1).reshape(-1)
-    b = jnp.concatenate([b_nn, a_nn, b_no, a_no], axis=1).reshape(-1)
-    dd = jnp.concatenate([dd_nn, dd_nn, dd_no, dd_no], axis=1).reshape(-1)
-    ok = jnp.concatenate([ok_nn, ok_nn, ok_no, ok_no], axis=1).reshape(-1)
+    a = flatten_rows(jnp.concatenate([a_nn, b_nn, a_no, b_no], axis=1))
+    b = flatten_rows(jnp.concatenate([b_nn, a_nn, b_no, a_no], axis=1))
+    dd = flatten_rows(jnp.concatenate([dd_nn, dd_nn, dd_no, dd_no], axis=1))
+    ok = flatten_rows(jnp.concatenate([ok_nn, ok_nn, ok_no, ok_no], axis=1))
 
     # ---- route updates to receiver owners, merge locally. The received
     # (receiver, candidate, dist) rows go through the fused knn_join
     # routing (invert incidences -> gather -> top-merge_k select) instead
     # of a (receiver, dist) lexsort — the same kernel family as the
     # single-chip local join.
-    k_u, key = jax.random.split(key)
-    payload = jnp.stack([a, b, _f32_bits(dd)], axis=1)
-    cap_u = max(4 * cfg.merge_k * max(n_local // max(P_, 1), 1), 8)
-    got = _all_to_all_route(payload, ok, a // n_local, P_, cap_u, axis, k_u)
-    r = got[:, 0]
+    r, got_b, got_d = _all_to_all_route(
+        (a, b, _f32_bits(dd)), ok, a // n_local, P_, axis)
     valid_r = r >= 0
     rl = jnp.where(valid_r, r - base, -1)
-    dd_r = jnp.where(valid_r, _bits_f32(got[:, 2]), jnp.inf)
+    dd_r = jnp.where(valid_r, _bits_f32(got_d), jnp.inf)
     # per-receiver source buffer: 2x the expected load (cap_u routes
     # ~4*merge_k rows per receiver on average). Overflow drops the
     # FARTHEST incoming rows per receiver (distance-prioritized, closing
@@ -339,7 +321,7 @@ def nn_descent_sharded_iteration(
     ok_r = rows_of >= 0
     safe_r = jnp.where(ok_r, rows_of, 0)
     gd = jnp.where(ok_r, dd_r[safe_r], jnp.inf)
-    gi = jnp.where(ok_r, got[:, 1][safe_r], -1)
+    gi = jnp.where(ok_r, got_b[safe_r], -1)
     cd, ci = ops.knn_join_select(
         gd, gi, jnp.full((n_local,), jnp.inf), c=cfg.merge_k,
         backend=cfg.backend,
@@ -374,44 +356,45 @@ def polish_sharded_round(
     psum'd over the mesh."""
     n_local, k = nl.idx.shape
     p = jax.lax.axis_index(axis)
-    base = p * n_local
-    my_ids = base + jnp.arange(n_local, dtype=jnp.int32)
+    my_ids = p * n_local + jnp.arange(n_local, dtype=jnp.int32)
 
-    ni = nl.idx                                           # (n_local, k)
-    cap_l = max(4 * (n_local * k) // max(P_, 1), 16)
-    lists, ok_l = fetch_rows_a2a(
-        nl.idx, ni.reshape(-1), axis=axis, P_=P_, n_local=n_local,
-        cap=cap_l,
-    )                                                     # (n_local*k, k)
-    nb = lists.reshape(n_local, k * k)
-    src_ok = jnp.broadcast_to(
-        ((ni >= 0) & ok_l.reshape(n_local, k))[:, :, None], (n_local, k, k)
-    ).reshape(n_local, k * k)
+    def chunk(args):
+        xr, x2r, myr, dr, ir, fr = args
+        # the chunk's neighbor lists, then its candidates' features; one
+        # bucket per destination holds the whole chunk's requests (no
+        # overflow, whatever the shard layout). Whole, the feature
+        # buckets were P * n_local * k*k * d floats (~27 GB at 32,768
+        # rows/chip, k=20, d=128), and the (n_local*k, k) list fetch
+        # compiled for minutes on TPU
+        lists, ok_l = fetch_rows_a2a(
+            nl.idx, ir.reshape(-1), axis=axis, P_=P_, n_local=n_local,
+            cap=ir.size,
+        )                                                 # (rows*k, k)
+        nb = lists.reshape(-1, k * k)
+        okr = jnp.repeat((ir >= 0) & ok_l.reshape(ir.shape), k, axis=1)
+        feats, ok_f = fetch_rows_a2a(
+            x_local, nb.reshape(-1), axis=axis, P_=P_, n_local=n_local,
+            cap=nb.size,
+        )                                                 # (rows*k*k, d)
+        okr = okr & (nb >= 0) & ok_f.reshape(nb.shape) & (nb != myr[:, None])
+        feats = feats.reshape(nb.shape + (-1,))
+        dd = x2r[:, None] + jnp.sum(feats * feats, axis=-1) - 2.0 * (
+            jnp.einsum("nd,ncd->nc", xr, feats,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+        )
+        dd = jnp.where(okr, jnp.maximum(dd, 0.0), jnp.inf)
+        cd, ci = ops.knn_join_select(
+            dd, jnp.where(okr, nb, -1), dr[:, -1], c=merge_c,
+            backend=backend,
+        )
+        sub, upd = heap.merge(NeighborLists(dr, ir, fr), cd, ci)
+        return sub, upd, jnp.sum(okr, axis=1)
 
-    cap_f = max(4 * (n_local * k * k) // max(P_, 1), 16)
-    feats, ok_f = fetch_rows_a2a(
-        x_local, nb.reshape(-1), axis=axis, P_=P_, n_local=n_local,
-        cap=cap_f,
-    )                                                     # (n_local*k*k, d)
-    ok = (
-        src_ok
-        & (nb >= 0)
-        & ok_f.reshape(n_local, k * k)
-        & (nb != my_ids[:, None])
-    )
-    feats = feats.reshape(n_local, k * k, -1)
-    dd = x2_local[:, None] + jnp.sum(feats * feats, axis=-1) - 2.0 * (
-        jnp.einsum("nd,ncd->nc", x_local, feats,
-                   preferred_element_type=jnp.float32)
-    )
-    dd = jnp.where(ok, jnp.maximum(dd, 0.0), jnp.inf)
-    evals = jnp.sum(ok)
-    cd, ci = ops.knn_join_select(
-        dd, jnp.where(ok, nb, -1), nl.dist[:, -1], c=merge_c,
-        backend=backend,
-    )
-    nl, upd = heap.merge(nl, cd, ci)
-    return nl, jax.lax.psum(jnp.sum(upd), axis), jax.lax.psum(evals, axis)
+    nl, upd, evals = chunked_rows(
+        chunk, (x_local, x2_local, my_ids, *nl), min(512, n_local))
+    return (nl, jax.lax.psum(jnp.sum(upd), axis),
+            jax.lax.psum(jnp.sum(evals), axis))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -545,6 +528,131 @@ class ShardBreaker:
             "probes": self.probes,
             "recoveries": self.recoveries,
         }
+
+
+# graph_search_sharded's shard_map bodies run under jit with their static
+# values as arguments: called bare, a shard_map body runs primitive by
+# primitive, a compile each.
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axis", "k_out", "cfg"))
+def _search_replicated(key, x, graph_idx, queries, live, *, mesh, axis,
+                       k_out, cfg):
+    """Replicated dispatch: every shard searches every query; one
+    all_gather + top-k folds the per-shard lists of the live shards."""
+    n_local = x.shape[0] // mesh.shape[axis]
+
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh,
+        in_specs=(P(), P(axis, None), P(axis, None), P(), P()),
+        out_specs=(P(), P()),
+        check_vma=False,
+    )
+    def fn(key, x_local, gi_local, q, live):
+        p = jax.lax.axis_index(axis)
+        base = p * n_local
+        kk = jax.random.fold_in(key, p)
+        d, i = graph_search(x_local, gi_local, q, k_out=k_out, key=kk,
+                            cfg=cfg)
+        gi = jnp.where(i >= 0, base + i, -1)
+        ds = jax.lax.all_gather(d, axis)             # (P, q, k_out)
+        is_ = jax.lax.all_gather(gi, axis)
+        # survivors-only merge: a dead shard's gathered list is
+        # masked out wholesale before the top-k fold
+        ds = jnp.where(live[:, None, None], ds, jnp.inf)
+        is_ = jnp.where(live[:, None, None], is_, -1)
+        alld = jnp.moveaxis(ds, 0, 1).reshape(q.shape[0], -1)
+        alli = jnp.moveaxis(is_, 0, 1).reshape(q.shape[0], -1)
+        alld = jnp.where(alli >= 0, alld, jnp.inf)
+        neg, pos = jax.lax.top_k(-alld, k_out)
+        out_i = jnp.take_along_axis(alli, pos, axis=1)
+        return jnp.where(out_i >= 0, -neg, jnp.inf), out_i
+
+    return fn(key, x, graph_idx, queries, live)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axis", "k_out",
+                                             "cap_q", "e_w", "cfg"))
+def _search_routed(key, x, graph_idx, queries, top_shards, entg, live, *,
+                   mesh, axis, k_out, cap_q, e_w, cfg):
+    """Routed dispatch: each shard searches the queries routed to it from
+    a (cap_q, d) buffer, seeded from ``entg``; each query folds only its
+    route_p shard lists."""
+    n_local = x.shape[0] // mesh.shape[axis]
+    q_n = queries.shape[0]
+
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh,
+        in_specs=(P(), P(axis, None), P(axis, None), P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P()),
+        check_vma=False,
+    )
+    def fn_routed(key, x_local, gi_local, q, tsh, eg, live):
+        p = jax.lax.axis_index(axis)
+        base = p * n_local
+        kk = jax.random.fold_in(key, p)
+        # queries routed to this shard, compacted into a cap_q buffer;
+        # a dead shard searches nothing (and its gathered buffer is
+        # excluded from every query's partial merge below)
+        mine = (tsh == p).any(axis=1) & live[p]           # (q,)
+        qids = jnp.nonzero(mine, size=cap_q, fill_value=-1)[0]
+        qids = qids.astype(jnp.int32)
+        ok_q = qids >= 0
+        safe_q = jnp.where(ok_q, qids, 0)
+        qsel = q[safe_q]                                  # (cap_q, d)
+        # this shard's slice of the routed entry candidates, local ids,
+        # valid entries compacted to the front (stable argsort)
+        egl = eg[safe_q] - base                           # (cap_q, t*m)
+        w = egl.shape[1]
+        ve = ok_q[:, None] & (eg[safe_q] >= 0) & (egl >= 0) & (egl < n_local)
+        ar = jnp.arange(w, dtype=jnp.int32)[None, :]
+        order = jnp.argsort(jnp.where(ve, ar, w + ar), axis=1)
+        ent = jnp.take_along_axis(jnp.where(ve, egl, -1), order, axis=1)
+        if w >= e_w:
+            ent = ent[:, :e_w]
+        else:
+            ent = jnp.pad(ent, ((0, 0), (0, e_w - w)), constant_values=-1)
+        # holes (few/no router members on this shard) fall back to a
+        # shard-local keyed draw — same no-replacement draw as the
+        # single-chip path
+        rnd = jax.lax.top_k(
+            jax.random.uniform(kk, (n_local,)), e_w
+        )[1].astype(jnp.int32)
+        ent = jnp.where(ent >= 0, ent, rnd[None, :])
+        d, i = graph_search(x_local, gi_local, qsel, k_out=k_out,
+                            entry=ent, key=kk, cfg=cfg)
+        gi = jnp.where((i >= 0) & ok_q[:, None], base + i, -1)
+        d = jnp.where(gi >= 0, d, jnp.inf)
+        # inverse map: query id -> its slot in this shard's buffer
+        gpos = jnp.full((q_n,), -1, jnp.int32).at[
+            jnp.where(ok_q, qids, q_n)
+        ].set(jnp.arange(cap_q, dtype=jnp.int32), mode="drop")
+        ds = jax.lax.all_gather(d, axis)                  # (P, cap_q, k)
+        is_ = jax.lax.all_gather(gi, axis)
+        gp = jax.lax.all_gather(gpos, axis)               # (P, q)
+        # partial merge: each query folds ONLY its route_p shard lists
+        pp = gp[tsh, jnp.arange(q_n)[:, None]]            # (q, p)
+        ppc = jnp.clip(pp, 0, cap_q - 1)
+        cd = ds[tsh, ppc]                                 # (q, p, k_out)
+        ci = is_[tsh, ppc]
+        hit = (pp >= 0)[:, :, None] & (ci >= 0) & live[tsh][:, :, None]
+        cd = jnp.where(hit, cd, jnp.inf).reshape(q_n, -1)
+        ci = jnp.where(hit, ci, -1).reshape(q_n, -1)
+        neg, pos = jax.lax.top_k(-cd, k_out)
+        out_i = jnp.take_along_axis(ci, pos, axis=1)
+        out_d = jnp.where(out_i >= 0, -neg, jnp.inf)
+        searched = jax.lax.psum(jnp.sum(ok_q.astype(jnp.int32)), axis)
+        routed_q = jax.lax.psum(jnp.sum(mine.astype(jnp.int32)), axis)
+        return out_d, out_i, searched, routed_q
+
+    return fn_routed(key, x, graph_idx, queries, top_shards, entg, live)
+
+
+def _static_cfg(cfg: SearchConfig) -> SearchConfig:
+    """The config as a cache key: the per-dispatch deadline is a host
+    knob that traced searches ignore, so it must not split the cache."""
+    return dataclasses.replace(cfg, max_rounds_deadline=0.0)
 
 
 def graph_search_sharded(
@@ -688,35 +796,10 @@ def graph_search_sharded(
         )
     routed = router is not None and 0 < route_p < P_
     if not routed:
-        @functools.partial(
-            shard_map,
-            mesh=mesh,
-            in_specs=(P(), P(axis, None), P(axis, None), P(), P()),
-            out_specs=(P(), P()),
-            check_vma=False,
-        )
-        def fn(key, x_local, gi_local, q, live):
-            p = jax.lax.axis_index(axis)
-            base = p * n_local
-            kk = jax.random.fold_in(key, p)
-            d, i = graph_search(x_local, gi_local, q, k_out=k_out, key=kk,
-                                cfg=cfg)
-            gi = jnp.where(i >= 0, base + i, -1)
-            ds = jax.lax.all_gather(d, axis)             # (P, q, k_out)
-            is_ = jax.lax.all_gather(gi, axis)
-            # survivors-only merge: a dead shard's gathered list is
-            # masked out wholesale before the top-k fold
-            ds = jnp.where(live[:, None, None], ds, jnp.inf)
-            is_ = jnp.where(live[:, None, None], is_, -1)
-            alld = jnp.moveaxis(ds, 0, 1).reshape(q.shape[0], -1)
-            alli = jnp.moveaxis(is_, 0, 1).reshape(q.shape[0], -1)
-            alld = jnp.where(alli >= 0, alld, jnp.inf)
-            neg, pos = jax.lax.top_k(-alld, k_out)
-            out_i = jnp.take_along_axis(alli, pos, axis=1)
-            return jnp.where(out_i >= 0, -neg, jnp.inf), out_i
-
         t0 = time.monotonic()
-        out_d, out_i = fn(key, x, graph_idx, queries, live_mask)
+        out_d, out_i = _search_replicated(
+            key, x, graph_idx, queries, live_mask, mesh=mesh, axis=axis,
+            k_out=k_out, cfg=_static_cfg(cfg))
         if breaker is not None:
             jax.block_until_ready(out_d)
             _breaker_feed(breaker, time.monotonic() - t0, P_, dead)
@@ -766,75 +849,10 @@ def graph_search_sharded(
     )
     cap_q = min(cap_q, q_n)
 
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(), P(axis, None), P(axis, None), P(), P(), P(), P()),
-        out_specs=(P(), P(), P(), P()),
-        check_vma=False,
-    )
-    def fn_routed(key, x_local, gi_local, q, tsh, eg, live):
-        p = jax.lax.axis_index(axis)
-        base = p * n_local
-        kk = jax.random.fold_in(key, p)
-        # queries routed to this shard, compacted into a cap_q buffer;
-        # a dead shard searches nothing (and its gathered buffer is
-        # excluded from every query's partial merge below)
-        mine = (tsh == p).any(axis=1) & live[p]           # (q,)
-        qids = jnp.nonzero(mine, size=cap_q, fill_value=-1)[0]
-        qids = qids.astype(jnp.int32)
-        ok_q = qids >= 0
-        safe_q = jnp.where(ok_q, qids, 0)
-        qsel = q[safe_q]                                  # (cap_q, d)
-        # this shard's slice of the routed entry candidates, local ids,
-        # valid entries compacted to the front (stable argsort)
-        egl = eg[safe_q] - base                           # (cap_q, t*m)
-        w = egl.shape[1]
-        ve = ok_q[:, None] & (eg[safe_q] >= 0) & (egl >= 0) & (egl < n_local)
-        ar = jnp.arange(w, dtype=jnp.int32)[None, :]
-        order = jnp.argsort(jnp.where(ve, ar, w + ar), axis=1)
-        ent = jnp.take_along_axis(jnp.where(ve, egl, -1), order, axis=1)
-        if w >= e_w:
-            ent = ent[:, :e_w]
-        else:
-            ent = jnp.pad(ent, ((0, 0), (0, e_w - w)), constant_values=-1)
-        # holes (few/no router members on this shard) fall back to a
-        # shard-local keyed draw — same no-replacement draw as the
-        # single-chip path
-        rnd = jax.lax.top_k(
-            jax.random.uniform(kk, (n_local,)), e_w
-        )[1].astype(jnp.int32)
-        ent = jnp.where(ent >= 0, ent, rnd[None, :])
-        d, i = graph_search(x_local, gi_local, qsel, k_out=k_out,
-                            entry=ent, key=kk, cfg=cfg)
-        gi = jnp.where((i >= 0) & ok_q[:, None], base + i, -1)
-        d = jnp.where(gi >= 0, d, jnp.inf)
-        # inverse map: query id -> its slot in this shard's buffer
-        gpos = jnp.full((q_n,), -1, jnp.int32).at[
-            jnp.where(ok_q, qids, q_n)
-        ].set(jnp.arange(cap_q, dtype=jnp.int32), mode="drop")
-        ds = jax.lax.all_gather(d, axis)                  # (P, cap_q, k)
-        is_ = jax.lax.all_gather(gi, axis)
-        gp = jax.lax.all_gather(gpos, axis)               # (P, q)
-        # partial merge: each query folds ONLY its route_p shard lists
-        pp = gp[tsh, jnp.arange(q_n)[:, None]]            # (q, p)
-        ppc = jnp.clip(pp, 0, cap_q - 1)
-        cd = ds[tsh, ppc]                                 # (q, p, k_out)
-        ci = is_[tsh, ppc]
-        hit = (pp >= 0)[:, :, None] & (ci >= 0) & live[tsh][:, :, None]
-        cd = jnp.where(hit, cd, jnp.inf).reshape(q_n, -1)
-        ci = jnp.where(hit, ci, -1).reshape(q_n, -1)
-        neg, pos = jax.lax.top_k(-cd, k_out)
-        out_i = jnp.take_along_axis(ci, pos, axis=1)
-        out_d = jnp.where(out_i >= 0, -neg, jnp.inf)
-        searched = jax.lax.psum(jnp.sum(ok_q.astype(jnp.int32)), axis)
-        routed_q = jax.lax.psum(jnp.sum(mine.astype(jnp.int32)), axis)
-        return out_d, out_i, searched, routed_q
-
     t0 = time.monotonic()
-    out_d, out_i, searched, routed_q = fn_routed(
-        key, x, graph_idx, queries, top_shards, entg, live_mask
-    )
+    out_d, out_i, searched, routed_q = _search_routed(
+        key, x, graph_idx, queries, top_shards, entg, live_mask, mesh=mesh,
+        axis=axis, k_out=k_out, cap_q=cap_q, e_w=e_w, cfg=_static_cfg(cfg))
     if breaker is not None:
         jax.block_until_ready(out_d)
         _breaker_feed(breaker, time.monotonic() - t0, P_, dead)
@@ -881,23 +899,26 @@ def _bits_f32(x):
     return jax.lax.bitcast_convert_type(x.astype(jnp.int32), jnp.float32)
 
 
-def _all_to_all_route(payload, mask, dest, P_, cap, axis, key):
-    """Route rows of ``payload`` (m, w) to shard ``dest`` (m,) over ``axis``.
-    Fixed per-destination capacity ``cap``; overflow rows are dropped
-    (sampling noise, same contract as buffer compaction elsewhere).
-    Returns (P_*cap, w) rows received, invalid rows marked by -1 in col 0."""
-    m, w = payload.shape
+def _all_to_all_route(cols, mask, dest, P_, axis):
+    """Route the rows of ``cols`` (a tuple of (m,) int32 columns) whose
+    ``mask`` is set to shard ``dest`` (m,) over ``axis``. Every bucket
+    holds all m rows, so nothing overflows: with cluster-aligned shards
+    nearly every row stays local, and buckets sized for a uniform spread
+    (m/P) dropped most of them. Returns the received columns, each
+    (P_*m,), invalid rows marked by -1 in the first. Each column travels
+    as its own flat array: a stacked (m, w) payload is a narrow array
+    whose gathers and relayouts compile for minutes on TPU."""
+    m = dest.shape[0]
     dest = jnp.where(mask, dest, P_)
-    rnd = jax.random.uniform(key, (m,))
-    order = jnp.lexsort((rnd, dest))
-    dest_s = dest[order]
-    pay_s = payload[order]
-    first = jnp.searchsorted(dest_s, jnp.arange(P_ + 1), side="left")
-    pos = jnp.arange(m) - first[jnp.clip(dest_s, 0, P_)]
-    buckets = jnp.full((P_, cap, w), -1, dtype=payload.dtype)
-    buckets = buckets.at[dest_s, pos].set(pay_s, mode="drop")
-    got = jax.lax.all_to_all(buckets, axis, split_axis=0, concat_axis=0, tiled=False)
-    return got.reshape(P_ * cap, w)
+    pos = _bucket_slots(dest, P_)
+    out = []
+    for col in cols:
+        buckets = jnp.full((P_, m), -1, dtype=col.dtype)
+        buckets = buckets.at[dest, pos].set(col, mode="drop")
+        got = jax.lax.all_to_all(buckets, axis, split_axis=0,
+                                 concat_axis=0, tiled=False)
+        out.append(got.reshape(P_ * m))
+    return tuple(out)
 
 
 def make_sharded_iteration_lowerable(mesh: Mesh, *, n: int, d: int, k: int,
@@ -920,7 +941,7 @@ def make_sharded_iteration_lowerable(mesh: Mesh, *, n: int, d: int, k: int,
     cfg = DescentConfig(k=k, rho=rho, reorder=False)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=flat,
         in_specs=(P(), P("data", None), P("data", None), P("data", None),
                   P("data", None)),
@@ -963,15 +984,20 @@ def build_knn_graph_sharded(
     key: jax.Array | None = None,
     axis: str = "data",
 ):
-    """Driver: sharded NN-Descent. Returns (dist, idx-global, iters)."""
+    """Sharded NN-Descent build. Returns (dist, idx-global, iters).
+
+    Every shard_map body below runs jitted: called bare, a shard_map
+    body runs primitive by primitive, each a compile of its own; jitted,
+    each body compiles once per build."""
     cfg = cfg or DescentConfig(k=k, reorder=False)
     key = jax.random.key(0) if key is None else key
     P_ = mesh.shape[axis]
     n, d = x.shape
     n_local = n // P_
 
+    @jax.jit
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(axis, None)),
         out_specs=(P(axis, None), P(axis, None), P()),
@@ -989,21 +1015,19 @@ def build_knn_graph_sharded(
         dist = jnp.maximum(
             jnp.sum(x_local * x_local, axis=1)[:, None]
             + jnp.sum(feats * feats, axis=-1)
-            - 2.0 * jnp.einsum("nd,nkd->nk", x_local, feats),
+            - 2.0 * jnp.einsum("nd,nkd->nk", x_local, feats,
+                               precision=jax.lax.Precision.HIGHEST),
             0.0,
         )
-        order = jnp.argsort(dist, axis=1)
-        return (
-            jnp.take_along_axis(dist, order, axis=1),
-            jnp.take_along_axis(idx, order, axis=1),
-            jnp.zeros((), jnp.int32),
-        )
+        dist, idx = jax.lax.sort((dist, idx), dimension=1, num_keys=1)
+        return dist, idx, jnp.zeros((), jnp.int32)
 
     dist0, idx0, _ = init_fn(key, x)
     nl = NeighborLists(dist0, idx0, jnp.ones_like(idx0, dtype=bool))
 
+    @jax.jit
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(), P(axis, None), P(axis, None), P(axis, None), P(axis, None),
@@ -1038,8 +1062,9 @@ def build_knn_graph_sharded(
 
     # terminal polish rounds (quality parity with the single-chip build:
     # see DescentConfig.polish / nn_descent.polish_iteration)
+    @jax.jit
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None), P(axis, None)),
         out_specs=(
@@ -1065,6 +1090,30 @@ def build_knn_graph_sharded(
         nl = NeighborLists(d_, i_, nf > 0)
         total_ev += int(ev_p)
         polish_updates.append(int(upd_p))
+
+    # returned distances are exact: difference form on the final lists
+    # (the single-chip build's nn_descent.rerank_lists, rows fetched by
+    # the request-routed all_to_all)
+    @jax.jit
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh,
+        in_specs=(P(axis, None), P(axis, None), P(axis, None)),
+        out_specs=(P(axis, None), P(axis, None), P(axis, None)),
+        check_vma=False,
+    )
+    def rerank_fn(x_local, i_, n_):
+        x_local = x_local.astype(jnp.float32)
+        feats, _ = fetch_rows_a2a(
+            x_local, i_.reshape(-1), axis=axis, P_=P_, n_local=n_local,
+            cap=i_.size,
+        )
+        dd = metric_mod.exact_sq_l2(
+            x_local, feats.reshape(i_.shape + (-1,)), i_)
+        return jax.lax.sort((dd, i_, n_), dimension=1, num_keys=1)
+
+    d_, i_, nf = rerank_fn(x, nl.idx, nl.new.astype(jnp.int8))
+    nl = NeighborLists(d_, i_, nf > 0)
     return nl.dist, nl.idx, {
         "iters": it + 1,
         "dist_evals": total_ev,

@@ -692,20 +692,16 @@ def _search_block(
         cond_fn, round_fn,
         (pool.dist, pool.idx, pool.new, jnp.zeros((), jnp.int32)),
     )
-    if quant:
-        # stage two: exact fp32 re-rank of the surviving pool with the
-        # EXISTING fp32 kernel — quantization decided pool membership
-        # (bounded recall noise), never the returned distances/order
-        safe_p = jnp.clip(pool_i, 0, n - 1)
-        dex = ops.knn_search_dists(
-            q, q2, x[safe_p], jnp.where(pool_i >= 0, x2[safe_p], 0.0),
-            pool_i, backend=cfg.backend,
-        )                                               # (qb, beam)
-        return ops.knn_join_select(
-            dex, pool_i, jnp.full((qb,), jnp.inf, jnp.float32), c=k_out,
-            backend=cfg.backend,
-        )
-    return pool_d[:, :k_out], pool_i[:, :k_out]
+    # exact re-rank of the final pool: difference-form fp32 distances on
+    # the gathered rows (metric.exact_sq_l2). For quantized scoring
+    # this is stage two — quantization decided pool membership (bounded
+    # recall noise), never the returned distances/order; for fp32 it
+    # replaces the traversal's norm-expansion values
+    dex = metric_mod.exact_sq_l2(q, x[jnp.clip(pool_i, 0, n - 1)], pool_i)
+    return ops.knn_join_select(
+        dex, pool_i, jnp.full((qb,), jnp.inf, jnp.float32), c=k_out,
+        backend=cfg.backend,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -804,7 +800,12 @@ def _graph_search_ref(
         # dead / hole entry points survive in the pool at distance _BIG;
         # never surface them
         out_i = jnp.where(out_d >= _BIG, -1, out_i)
-        return out_d, out_i
+        # returned distances are exact: difference form, re-sorted
+        dex = metric_mod.exact_sq_l2(q, x[jnp.clip(out_i, 0, n - 1)],
+                                     out_i)
+        out_d = jnp.where(out_i >= 0, dex, out_d)
+        order = jnp.argsort(out_d, stable=True)
+        return out_d[order], out_i[order]
 
     q2 = jnp.sum(queries * queries, axis=1)
     if filt is None:

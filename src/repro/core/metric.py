@@ -177,3 +177,13 @@ def filter_frac(filter_ids: jax.Array | None, n: int | None = None) -> float:
     if filter_ids is None:
         return 1.0
     return float(jnp.mean(jnp.asarray(filter_ids, bool)))
+
+
+def exact_sq_l2(q: jax.Array, rows: jax.Array, ids: jax.Array):
+    """Difference-form squared l2 of each query against its gathered
+    rows: q (..., dp), rows (..., W, dp), ids (..., W) -> (..., W), +inf
+    where ``ids`` < 0. Summing (r - q)^2 has none of the norm
+    expansion's cancellation, so near neighbors get exact fp32
+    distances — the form every returned distance is computed in."""
+    diff = rows - q[..., None, :]
+    return jnp.where(ids >= 0, jnp.sum(diff * diff, axis=-1), jnp.inf)

@@ -36,10 +36,10 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core import heap, quantize, selection
+from repro.core import heap, quantize, selection, sorting
 from repro.core import metric as metric_mod
 from repro.core.heap import NeighborLists
-from repro.core.layout import pad_features
+from repro.core.layout import chunked_rows, flatten_rows, pad_features
 from repro.core.quantize import QuantizedStore
 from repro.core.reorder import apply_permutation, greedy_reorder
 from repro.kernels import ops
@@ -129,7 +129,8 @@ _SELECT: dict[str, Callable] = {
 def pair_block(xg: jax.Array, x2g: jax.Array, yg: jax.Array, y2g: jax.Array):
     """Batched norm-expansion distances: (n,a,d)x(n,b,d) -> (n,a,b)."""
     ab = jnp.einsum(
-        "nad,nbd->nab", xg, yg, preferred_element_type=jnp.float32
+        "nad,nbd->nab", xg, yg, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     out = x2g[:, :, None] + y2g[:, None, :] - 2.0 * ab
     return jnp.maximum(out, 0.0)
@@ -140,7 +141,7 @@ def compact_pairs(recv, cand, dist, n: int, c: int):
     (n, c) buffers keeping the c best (smallest distance) per receiver."""
     valid = recv >= 0
     key_recv = jnp.where(valid, recv, n)
-    order = jnp.lexsort((dist, key_recv))
+    order = sorting.lex_argsort(sorting.sortable_bits(dist), key_recv)
     recv_s = key_recv[order]
     cand_s = cand[order]
     dist_s = dist[order]
@@ -160,24 +161,26 @@ def invert_candidates(
     """Invert (row -> candidate) incidences: for every candidate id in
     [0, n_univ), the (row, slot) positions that list it, compacted into
     (n_univ, src_cap) padded buffers (-1 tail). Overflow beyond src_cap:
-    with ``prio`` (same shape as ``cands``, e.g. a distance) the LOWEST
-    priority incidences are kept per candidate — the old smallest-
+    with ``prio`` (same shape as ``cands``, a non-negative distance) the
+    LOWEST priority incidences are kept per candidate — the old smallest-
     (row, slot) policy was a systematic bias against late rows on
     hub-heavy buffers; without ``prio`` the old deterministic id order
     is preserved (pure adjacency inversions have no distance to rank by).
 
-    One stable (arg|lex)sort of the n*C incidence ids — the only sort
-    left in the fused build hot path, ~pairs/C times smaller than the
-    retired global pair sort."""
+    One single-key sort of the n*C incidence ids — the only sort left in
+    the fused build hot path, ~pairs/C times smaller than the retired
+    global pair sort. The priority rides in the low bits of the packed
+    key (core/sorting.py), so it ranks to about 1/128 relative; near-ties
+    fall in no set order."""
     nr, c = cands.shape
-    flat = cands.reshape(-1)
+    flat = flatten_rows(cands)
     key = jnp.where(flat >= 0, flat, n_univ)
     if prio is None:
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
     else:
-        # candidate-major, priority-minor; lexsort is stable so equal
-        # priorities still fall back to the old (row, slot) order
-        order = jnp.lexsort((prio.reshape(-1), key)).astype(jnp.int32)
+        # candidate-major, priority-minor
+        order = sorting.packed_argsort(
+            key, sorting.sortable_bits(flatten_rows(prio)), n_univ)
     rs = key[order]
     first = jnp.searchsorted(rs, jnp.arange(n_univ + 1))
     pos = jnp.arange(nr * c) - first[jnp.clip(rs, 0, n_univ)]
@@ -212,24 +215,29 @@ def local_join_fused(
     valid = cands >= 0
     safe = jnp.where(valid, cands, 0)
     ids = jnp.where(valid, cands, -1)
-    if cfg.precision != "f32" and qs is not None:
-        x2g = jnp.where(valid, qs.x2[safe], 0.0)
-        if cfg.precision == "int8":
-            dists, ev = ops.knn_join_dists_q8(
-                qs.data[safe], qs.scale[safe], x2g, ids, cn=cn.shape[1],
-                backend=cfg.backend,
-            )                                        # (n, C, C), (n,)
-        else:
-            dists, ev = ops.knn_join_dists_bf16(
-                qs.data[safe], x2g, ids, cn=cn.shape[1],
-                backend=cfg.backend,
+    def join_rows(args):
+        sf, vl, idr = args
+        if cfg.precision != "f32" and qs is not None:
+            x2g = jnp.where(vl, qs.x2[sf], 0.0)
+            if cfg.precision == "int8":
+                return ops.knn_join_dists_q8(
+                    qs.data[sf], qs.scale[sf], x2g, idr, cn=cn.shape[1],
+                    backend=cfg.backend,
+                )
+            return ops.knn_join_dists_bf16(
+                qs.data[sf], x2g, idr, cn=cn.shape[1], backend=cfg.backend,
             )
-    else:
-        xg = x[safe]                                 # (n, C, dp)
-        x2g = jnp.where(valid, x2[safe], 0.0)
-        dists, ev = ops.knn_join_dists(
-            xg, x2g, ids, cn=cn.shape[1], backend=cfg.backend
-        )                                            # (n, C, C), (n,)
+        x2g = jnp.where(vl, x2[sf], 0.0)
+        return ops.knn_join_dists(
+            x[sf], x2g, idr, cn=cn.shape[1], backend=cfg.backend
+        )
+
+    # the (rows, C, dp) feature gather feeding the kernel goes
+    # join_chunk rows at a time: whole, it is n*C*dp*4 bytes (8.6 GB at
+    # 60,000 x 784 with C=40 — past a 16 GB chip with the rest of the
+    # iteration)
+    r = min(cfg.join_chunk, ((n + 7) // 8) * 8)
+    dists, ev = chunked_rows(join_rows, (safe, valid, ids), r)
 
     kth = nl.dist[:, -1]
     s_cap = cfg.join_src or 2 * c_all
@@ -242,7 +250,6 @@ def local_join_fused(
 
     # receiver chunks: pad everything to a chunk multiple so every merge
     # is a full in-bounds block (padding rows have no incidences -> no-op)
-    r = min(cfg.join_chunk, ((n + 7) // 8) * 8)
     npad = ((n + r - 1) // r) * r
     pad = npad - n
     rows_of = jnp.pad(rows_of, ((0, pad), (0, 0)), constant_values=-1)
@@ -325,10 +332,10 @@ def nn_descent_iteration(
         & (a_no != b_no)
     )
 
-    a = jnp.concatenate([a_nn, b_nn, a_no, b_no], axis=1).reshape(-1)
-    b = jnp.concatenate([b_nn, a_nn, b_no, a_no], axis=1).reshape(-1)
-    dd = jnp.concatenate([dd_nn, dd_nn, dd_no, dd_no], axis=1).reshape(-1)
-    ok = jnp.concatenate([ok_nn, ok_nn, ok_no, ok_no], axis=1).reshape(-1)
+    a = flatten_rows(jnp.concatenate([a_nn, b_nn, a_no, b_no], axis=1))
+    b = flatten_rows(jnp.concatenate([b_nn, a_nn, b_no, a_no], axis=1))
+    dd = flatten_rows(jnp.concatenate([dd_nn, dd_nn, dd_no, dd_no], axis=1))
+    ok = flatten_rows(jnp.concatenate([ok_nn, ok_nn, ok_no, ok_no], axis=1))
 
     # receiver-side prefilter: only pairs beating the receiver's current
     # k-th distance can change the graph (saves the sort+merge cost)
@@ -368,55 +375,88 @@ def polish_iteration(
     backend="ref" keeps the direct full-width merge (oracle).
     """
     n, k = nl.idx.shape
-    ni = nl.idx
-    nb = ni[jnp.clip(ni, 0, n - 1)].reshape(n, k * k)
-    rows = jnp.arange(n, dtype=jnp.int32)[:, None]
-    src_ok = jnp.broadcast_to(
-        (ni >= 0)[:, :, None], (n, k, k)
-    ).reshape(n, k * k)
-    ok = src_ok & (nb >= 0) & (nb != rows)
-    cx = x[jnp.clip(nb, 0, n - 1)]
-    dd = x2[:, None] + x2[jnp.clip(nb, 0, n - 1)] - 2.0 * jnp.einsum(
-        "nd,ncd->nc", x, cx, preferred_element_type=jnp.float32
-    )
-    dd = jnp.where(ok, jnp.maximum(dd, 0.0), jnp.inf)
-    evals = jnp.sum(ok)
-    if backend == "ref":
-        nl, upd = heap.merge(nl, dd, jnp.where(ok, nb, -1))
-        return nl, jnp.sum(upd), evals
-    cd, ci = ops.knn_join_select(
-        dd, jnp.where(ok, nb, -1), nl.dist[:, -1],
-        c=min(6 * k, k * k), backend=backend,
-    )
-    nl, upd = heap.merge(nl, cd, ci)
-    return nl, jnp.sum(upd), evals
+
+    def rows(args):
+        xr, x2r, rr, dr, ir, fr = args
+        nb = nl.idx[jnp.clip(ir, 0, n - 1)].reshape(-1, k * k)
+        src_ok = jnp.repeat(ir >= 0, k, axis=1)
+        ok = src_ok & (nb >= 0) & (nb != rr[:, None])
+        safe = jnp.clip(nb, 0, n - 1)
+        ab = jnp.einsum("nd,ncd->nc", xr, x[safe],
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)
+        dd = jnp.where(ok, jnp.maximum(x2r[:, None] + x2[safe] - 2.0 * ab,
+                                       0.0), jnp.inf)
+        sub = NeighborLists(dr, ir, fr)
+        if backend == "ref":
+            sub, upd = heap.merge(sub, dd, jnp.where(ok, nb, -1))
+        else:
+            cd, ci = ops.knn_join_select(
+                dd, jnp.where(ok, nb, -1), dr[:, -1],
+                c=min(6 * k, k * k), backend=backend,
+            )
+            sub, upd = heap.merge(sub, cd, ci)
+        return sub, upd, jnp.sum(ok, axis=1)
+
+    # 512 rows at a time: the whole (n, k*k, dp) gather is 86 GB at
+    # 60,000 x 784, k=20, and whole-array (n, k*k) gathers and sorts
+    # compiled for 232.9 s at 131,072 x 128 for a v5e
+    nl, upd, evals = chunked_rows(
+        rows, (x, x2, jnp.arange(n, dtype=jnp.int32), *nl),
+        min(512, ((n + 7) // 8) * 8))
+    return nl, jnp.sum(upd), jnp.sum(evals)
 
 
-@functools.partial(jax.jit, static_argnames=("backend",))
-def rerank_lists(
-    x: jax.Array,          # (n, d) — feature-padded
-    x2: jax.Array,         # (n,) cached squared norms
-    nl: NeighborLists,
-    backend: str = "auto",
-):
+@jax.jit
+def rerank_lists(x: jax.Array, nl: NeighborLists):
     """Exact fp32 re-rank of every neighbor list: recompute d(row, idx)
-    with the EXISTING fp32 serving kernel (one (n, k) blocked tile) and
-    re-sort each row. The second stage of a quantized build — quantized
-    scoring decides which edges survive (bounded recall noise), this pass
-    makes the stored distances and within-row order exact before the fp32
-    polish rounds extend them. Cost: n*k distance evaluations."""
+    in difference form (``metric.exact_sq_l2``) and re-sort each row.
+    Run after the quantized sampled iterations (quantized scoring
+    decides which edges survive, this pass makes the stored distances
+    and order exact before the polish rounds extend them) and once at
+    the end of every build, so the returned graph never carries a
+    norm-expansion value. Rows go 1024 at a time, bounding the
+    (rows, k, dp) gather; the re-sort runs inside each chunk (over whole
+    (n, k) arrays its gathers compiled for 164.2 s at 131,072 rows for a
+    v5e). Cost: n*k distance evaluations."""
     n, k = nl.idx.shape
-    safe = jnp.clip(nl.idx, 0, n - 1)
-    dd = ops.knn_search_dists(
-        x, x2, x[safe], jnp.where(nl.idx >= 0, x2[safe], 0.0), nl.idx,
-        backend=backend,
-    )                                                 # (n, k)
-    order = jnp.argsort(dd, axis=1, stable=True)      # +inf (invalid) last
-    return NeighborLists(
-        jnp.take_along_axis(dd, order, axis=1),
-        jnp.take_along_axis(nl.idx, order, axis=1),
-        jnp.take_along_axis(nl.new, order, axis=1),
-    )
+
+    def rows(args):
+        xr, ir, fr = args
+        dd = metric_mod.exact_sq_l2(xr, x[jnp.clip(ir, 0, n - 1)], ir)
+        # stable, +inf (invalid) last
+        return jax.lax.sort((dd, ir, fr), dimension=1, num_keys=1)
+
+    return NeighborLists(*chunked_rows(
+        rows, (x, nl.idx, nl.new), min(1024, ((n + 7) // 8) * 8)))
+
+
+# the build loop's own array bookkeeping runs as jitted programs: op by
+# op, each large gather/scatter was a TPU compile of its own (about 15 s
+# apiece at 60,000 rows)
+
+@jax.jit
+def _take_rows(rows: jax.Array, arrays):
+    """``a[rows]`` for every array of a pytree."""
+    return jax.tree_util.tree_map(lambda a: a[rows], arrays)
+
+
+@jax.jit
+def _to_original_ids(nl: NeighborLists, perm: jax.Array):
+    """Map lists back to original ids: row r describes original node
+    perm[r]. Returns (dist, idx)."""
+    n = perm.shape[0]
+    inv = jnp.zeros_like(perm).at[perm].set(
+        jnp.arange(n, dtype=perm.dtype))
+
+    # 1024 rows at a time: whole-array gathers of the narrow (n, k)
+    # lists compile for minutes on TPU
+    def rows(a):
+        ir = nl.idx[a[0]]
+        return nl.dist[a[0]], jnp.where(
+            ir >= 0, perm[jnp.clip(ir, 0, n - 1)], -1)
+
+    return chunked_rows(rows, (inv,), min(1024, ((n + 7) // 8) * 8))
 
 
 def build_knn_graph(
@@ -477,13 +517,8 @@ def build_knn_graph(
         if cfg.reorder and it + 1 == cfg.reorder_after:
             sigma, sigma_inv = greedy_reorder(nl)
             xp, nl = apply_permutation(xp, nl, sigma, sigma_inv)
-            x2 = x2[sigma_inv]
-            perm = perm[sigma_inv]
-            if quant:
-                # per-row quantization permutes exactly — no requantize
-                qs = QuantizedStore(qs.data[sigma_inv],
-                                    qs.scale[sigma_inv],
-                                    qs.x2[sigma_inv])
+            # per-row quantization permutes exactly — no requantize
+            x2, perm, qs = _take_rows(sigma_inv, (x2, perm, qs))
             stats.reordered = True
         if upd <= cfg.delta * n * cfg.k:
             break
@@ -493,7 +528,7 @@ def build_knn_graph(
     # lists, so the polish rounds below merge against exact distances and
     # the returned graph never carries a quantized value
     if quant:
-        nl = rerank_lists(xp, x2, nl, cfg.backend)
+        nl = rerank_lists(xp, nl)
         stats.dist_evals += n * cfg.k
 
     # terminal polish (see DescentConfig.polish / polish_iteration)
@@ -503,10 +538,8 @@ def build_knn_graph(
         polish_updates.append(int(upd_p))
         stats.dist_evals += int(ev_p)
     stats.polish_updates = tuple(polish_updates)
+    # returned distances are exact: difference form on the final lists
+    nl = rerank_lists(xp, nl)
 
-    # map back to original ids: row r describes original node perm[r]
-    dist = jnp.zeros_like(nl.dist).at[perm].set(nl.dist)
-    idx = jnp.full_like(nl.idx, -1).at[perm].set(
-        jnp.where(nl.idx >= 0, perm[jnp.clip(nl.idx, 0, n - 1)], -1)
-    )
+    dist, idx = _to_original_ids(nl, perm)
     return dist, idx, stats

@@ -704,12 +704,14 @@ def _maybe_rebuild_router(
     w.r.t. assignments/members, but the CENTROIDS slowly stop describing
     the data as the corpus churns — past the drift threshold, refit.
 
-    A failed rebuild degrades, never crashes: the incremental router is
-    stale but still *correct* as an entry-point heuristic (holes fall
-    back to random draws inside graph_search), so the store keeps
-    serving from it — degraded recall beats a dead insert path. The
-    rebuild is re-attempted on the next insert that crosses the
-    threshold."""
+    A failed rebuild (an ``OSError`` — the ``router.rebuild`` fault site
+    models the I/O-class failures a refit can hit) degrades, never
+    crashes: the incremental router is stale but still *correct* as an
+    entry-point heuristic (holes fall back to random draws inside
+    graph_search), so the store keeps serving from it — degraded recall
+    beats a dead insert path. The rebuild is re-attempted on the next
+    insert that crosses the threshold. Any other error (a compile or
+    device failure inside ``build_router``) propagates."""
     rcfg = cfg.router or RouterConfig()
     if needs_rebuild(router, int(jnp.sum(alive)), rcfg):
         try:
@@ -718,7 +720,7 @@ def _maybe_rebuild_router(
                 x, cfg=rcfg, key=key, alive=alive, x2=x2,
                 backend=cfg.backend,
             )
-        except Exception as e:
+        except OSError as e:
             warnings.warn(
                 f"router rebuild failed ({e}); serving continues from "
                 "the stale router", RuntimeWarning, stacklevel=2)

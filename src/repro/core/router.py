@@ -63,11 +63,28 @@ def resolve_centroids(live: int, cfg: RouterConfig) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("c", "iters"))
-def _lloyd(xs: jax.Array, c: int, iters: int) -> jax.Array:
-    """Lloyd's k-means on the (already sampled) rows. Empty clusters keep
-    their previous centroid — with a random-shuffled init that is rare
-    and harmless (the empty centroid simply attracts no entries)."""
-    cent = xs[:c]
+def _lloyd(xs: jax.Array, c: int, iters: int, key: jax.Array) -> jax.Array:
+    """Lloyd's k-means on the (already sampled) rows, seeded by k-means++:
+    each seed after the first is drawn with probability proportional to
+    its squared distance from the seeds so far, so well-separated
+    clusters each get one. Seeding with the first c sampled rows left
+    about a third of the clusters without a centroid when c is near
+    their number (1 - 1/e coverage), and their queries then routed to
+    the wrong shards. Empty clusters keep their previous centroid (the
+    empty centroid simply attracts no entries)."""
+    x2 = jnp.sum(xs * xs, 1)
+
+    def sq_dist(v):
+        return jnp.maximum(x2 - 2.0 * xs @ v + jnp.sum(v * v), 0.0)
+
+    def seed(i, carry):
+        cent, dmin = carry
+        j = jax.random.categorical(jax.random.fold_in(key, i),
+                                   jnp.log(jnp.maximum(dmin, 1e-30)))
+        return cent.at[i].set(xs[j]), jnp.minimum(dmin, sq_dist(xs[j]))
+
+    cent = jnp.zeros((c, xs.shape[1]), xs.dtype).at[0].set(xs[0])
+    cent, _ = jax.lax.fori_loop(1, c, seed, (cent, sq_dist(xs[0])))
 
     def body(cent, _):
         d = jnp.maximum(
@@ -138,7 +155,8 @@ def build_router(
         w = jnp.where(alive, w, -1.0)
     wv, sample_ids = jax.lax.top_k(w, s)
     sample_ids = jnp.where(wv > 0.0, sample_ids, sample_ids[0])
-    cent = _lloyd(x[sample_ids], min(c, s), cfg.iters)
+    cent = _lloyd(x[sample_ids], min(c, s), cfg.iters,
+                  jax.random.fold_in(key, 1))
     if cent.shape[0] < c:      # degenerate tiny corpus: pad with repeats
         cent = jnp.concatenate(
             [cent, jnp.broadcast_to(cent[:1], (c - cent.shape[0], cent.shape[1]))]

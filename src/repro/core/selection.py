@@ -25,7 +25,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import sorting
 from repro.core.heap import NeighborLists
+from repro.core.layout import flatten_rows
 
 
 class Candidates(NamedTuple):
@@ -41,14 +43,13 @@ def _incidences(nl: NeighborLists):
     Shapes: (2*n*k,) flattened, slot index retained for flag clearing.
     """
     n, k = nl.idx.shape
-    rows = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, k))
     valid = nl.idx >= 0
-    fwd_recv = rows.reshape(-1)
-    fwd_cand = jnp.where(valid, nl.idx, 0).reshape(-1)
+    fwd_recv = jnp.arange(n * k, dtype=jnp.int32) // k
+    fwd_cand = flatten_rows(jnp.where(valid, nl.idx, 0))
     rev_recv = fwd_cand
     rev_cand = fwd_recv
-    is_new = nl.new.reshape(-1)
-    valid = valid.reshape(-1)
+    is_new = flatten_rows(nl.new)
+    valid = flatten_rows(valid)
     recv = jnp.concatenate([fwd_recv, rev_recv])
     cand = jnp.concatenate([fwd_cand, rev_cand])  # candidate for receiver
     new = jnp.concatenate([is_new, is_new])
@@ -70,9 +71,13 @@ def _compact(
 ) -> jax.Array:
     """Compact accepted (receiver, candidate) incidences into an (n, c)
     buffer: sort by (receiver, random), keep the first c per receiver.
-    This is exact uniform reservoir sampling of the accepted set."""
+    This is uniform reservoir sampling of the accepted set. The random
+    order is quantized into the low bits of one packed int32 sort key
+    (core/sorting.py: a two-key lexsort compiles for minutes on TPU);
+    equal draws tie in no set order, which is still a uniform draw."""
     key_recv = jnp.where(accept, recv, n)  # rejected sort to the end
-    order = jnp.lexsort((rnd, key_recv))
+    rank = (rnd * 2.0**31).astype(jnp.int32)
+    order = sorting.packed_argsort(key_recv, rank, n)
     recv_s = key_recv[order]
     cand_s = cand[order]
     # position within the receiver's group

@@ -22,9 +22,59 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
 
-DEFAULT_TM = 256
+
 _BIG = float(jnp.finfo(jnp.float32).max)
+
+
+def block_rows(n: int, k: int, c: int) -> int:
+    """Rows per block of the merge/compact kernels, from VMEM bytes: the
+    double-buffered (k,) list rows, (c,) candidate rows and outputs, plus
+    about eight (k + c,) temporaries of the selection network."""
+    io = 5 * tiling.row_bytes(k, jnp.float32) + 2 * tiling.row_bytes(
+        c, jnp.float32) + tiling.row_bytes(1, jnp.int32)
+    temps = 8 * tiling.row_bytes(k + c, jnp.float32)
+    return tiling.rows_per_block(2 * io + temps, n)
+
+
+def select_ascending(pool, ids, steps: int, flag=None):
+    """The ``steps`` smallest entries of each row of ``pool`` (TM, P), in
+    ascending order, ties to the lowest position — the min-extraction
+    network every selection kernel of this package runs. Each step takes
+    the row minimum, its first position (a min over the lane iota, no
+    gather), and masks it out. A ``fori_loop`` with 2-D carries keeps
+    the VMEM footprint independent of ``steps``.
+
+    Returns (dist, ids, flag) of the picks, each (TM, steps): raw
+    minima (``_BIG`` once a row runs dry), the ``ids`` payload at the
+    pick, and the int32 ``flag`` payload at the pick (0 without one, and
+    0 for a position picked before, so a dry row reports no flag)."""
+    tm, p = pool.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tm, p), 1)
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, (tm, steps), 1)
+
+    def step(t, carry):
+        pool, flag, od, oi, of = carry
+        dmin = jnp.min(pool, axis=1, keepdims=True)
+        pos = jnp.min(jnp.where(pool == dmin, lane, p), axis=1,
+                      keepdims=True)
+        onehot = lane == pos
+        at = out_lane == t
+        od = jnp.where(at, dmin, od)
+        oi = jnp.where(at, jnp.sum(jnp.where(onehot, ids, 0), axis=1,
+                                   keepdims=True), oi)
+        if flag is not None:
+            of = jnp.where(at, jnp.sum(jnp.where(onehot, flag, 0), axis=1,
+                                       keepdims=True), of)
+            flag = jnp.where(onehot, 0, flag)
+        return jnp.where(onehot, _BIG, pool), flag, od, oi, of
+
+    init = (pool, flag, jnp.full((tm, steps), _BIG, jnp.float32),
+            jnp.zeros((tm, steps), jnp.int32),
+            jnp.zeros((tm, steps), jnp.int32))
+    _, _, od, oi, of = jax.lax.fori_loop(0, steps, step, init)
+    return od, oi, of
 
 
 def _merge_kernel(cd_ref, ci_ref, qd_ref, qi_ref, od_ref, oi_ref, upd_ref, *, k: int):
@@ -33,46 +83,49 @@ def _merge_kernel(cd_ref, ci_ref, qd_ref, qi_ref, od_ref, oi_ref, upd_ref, *, k:
     cand_d = qd_ref[...]         # (TM, C)
     cand_i = qi_ref[...]         # (TM, C)
 
-    # --- dedup: candidate already in list, duplicate candidate, or invalid
-    dup = cand_i < 0
-    for j in range(k):
-        dup |= cand_i == cur_i[:, j][:, None]
+    # --- dedup: candidate already in list, duplicate of an earlier
+    # candidate, or invalid. Column-at-a-time compares in fori_loops keep
+    # every mask 2-D (Mosaic lowers no (TM, C, C) boolean tensor) and the
+    # VMEM footprint independent of k and C; column j is taken by a
+    # one-hot lane sum
     c = cand_d.shape[1]
-    eq = cand_i[:, :, None] == cand_i[:, None, :]
-    earlier = jnp.tril(jnp.ones((c, c), dtype=bool), k=-1)[None]
-    dup |= (eq & earlier).any(-1)
-    cand_d = jnp.where(dup, _BIG, cand_d)
+    cur_lane = jax.lax.broadcasted_iota(jnp.int32, cur_i.shape, 1)
+    cand_lane = jax.lax.broadcasted_iota(jnp.int32, cand_i.shape, 1)
 
-    # --- k-step vectorized min-extraction merge (iota+select one-hot form:
-    # no gathers/fancy indexing, so every step stays VPU-native)
-    pool_d = jnp.concatenate([jnp.where(jnp.isinf(cur_d), _BIG, cur_d), cand_d], axis=1)
+    def column(a, lane, j):
+        return jnp.sum(jnp.where(lane == j, a, 0), axis=1, keepdims=True)
+
+    def mark(hit):      # loop carries stay int32: Mosaic carries no i1
+        return hit.astype(jnp.int32)
+
+    dup = jax.lax.fori_loop(
+        0, k, lambda j, dup: dup | mark(
+            cand_i == column(cur_i, cur_lane, j)),
+        mark(cand_i < 0))
+    dup = jax.lax.fori_loop(
+        0, c - 1, lambda j, dup: dup | mark(
+            (cand_i == column(cand_i, cand_lane, j)) & (cand_lane > j)),
+        dup)
+    cand_d = jnp.where(dup > 0, _BIG, cand_d)
+
+    # --- k-step min-extraction over the current list + candidates
+    pool_d = jnp.concatenate(
+        [jnp.where(jnp.isinf(cur_d), _BIG, cur_d), cand_d], axis=1)
     pool_i = jnp.concatenate([cur_i, cand_i], axis=1)
-    is_cand = jnp.concatenate(
-        [jnp.zeros(cur_d.shape, bool), jnp.ones(cand_d.shape, bool)], axis=1
-    )
     lane = jax.lax.broadcasted_iota(jnp.int32, pool_d.shape, 1)
-    out_d = []
-    out_i = []
-    n_upd = jnp.zeros((cur_d.shape[0],), jnp.int32)
-    for _t in range(k):
-        amin = jnp.argmin(pool_d, axis=1)                      # (TM,)
-        onehot = lane == amin[:, None]
-        dmin = jnp.min(pool_d, axis=1)
-        imin = jnp.sum(jnp.where(onehot, pool_i, 0), axis=1)
-        took_cand = jnp.any(onehot & is_cand, axis=1) & (dmin < _BIG)
-        n_upd += took_cand.astype(jnp.int32)
-        out_d.append(jnp.where(dmin < _BIG, dmin, jnp.inf))
-        out_i.append(jnp.where(dmin < _BIG, imin, -1))
-        pool_d = jnp.where(onehot, _BIG, pool_d)
-    od_ref[...] = jnp.stack(out_d, axis=1)
-    oi_ref[...] = jnp.stack(out_i, axis=1)
-    upd_ref[...] = n_upd[:, None]
+    d, i, took_cand = select_ascending(pool_d, pool_i, k,
+                                       (lane >= k).astype(jnp.int32))
+    hit = d < _BIG
+    od_ref[...] = jnp.where(hit, d, jnp.inf)
+    oi_ref[...] = jnp.where(hit, i, -1)
+    upd_ref[...] = jnp.sum((hit & (took_cand > 0)).astype(jnp.int32),
+                           axis=1, keepdims=True)
 
 
 def _compact_kernel(cd_ref, ci_ref, dr_ref, od_ref, oi_ref, rm_ref, *, k: int):
     """Drop masked entries from sorted rows, keeping the survivors sorted
     and packed to the front — the tombstone-purge primitive of the online
-    subsystem (core/online.py). Same k-step min-extraction network as
+    subsystem (core/online.py). Same min-extraction network as
     ``_merge_kernel``: no gathers, VPU-native."""
     cur_d = cd_ref[...]                 # (TM, K) ascending
     cur_i = ci_ref[...]                 # (TM, K)
@@ -86,22 +139,10 @@ def _compact_kernel(cd_ref, ci_ref, dr_ref, od_ref, oi_ref, rm_ref, *, k: int):
     # entries at placeholder distances (heap.init_random's 3e38) survive
     # exactly as in the ref.knn_compact oracle
     keep = ~drop & valid & jnp.isfinite(cur_d)
-    pool_d = jnp.where(keep, cur_d, _BIG)
-    lane = jax.lax.broadcasted_iota(jnp.int32, pool_d.shape, 1)
-    out_d = []
-    out_i = []
-    for _t in range(k):
-        amin = jnp.argmin(pool_d, axis=1)
-        onehot = lane == amin[:, None]
-        dmin = jnp.min(pool_d, axis=1)
-        imin = jnp.sum(jnp.where(onehot, cur_i, 0), axis=1)
-        real = jnp.any(onehot & keep, axis=1)
-        out_d.append(jnp.where(real, dmin, jnp.inf))
-        out_i.append(jnp.where(real, imin, -1))
-        pool_d = jnp.where(onehot, _BIG, pool_d)
-        keep &= ~onehot
-    od_ref[...] = jnp.stack(out_d, axis=1)
-    oi_ref[...] = jnp.stack(out_i, axis=1)
+    d, i, real = select_ascending(jnp.where(keep, cur_d, _BIG), cur_i, k,
+                                  keep.astype(jnp.int32))
+    od_ref[...] = jnp.where(real > 0, d, jnp.inf)
+    oi_ref[...] = jnp.where(real > 0, i, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "interpret"))
@@ -110,7 +151,7 @@ def knn_compact_blocked(
     cur_idx: jax.Array,    # (n, k) int32, -1 = empty
     drop: jax.Array,       # (n, k) bool — entries to remove
     *,
-    tm: int = DEFAULT_TM,
+    tm: int | None = None,    # rows per block (None: from VMEM bytes)
     interpret: bool = False,
 ):
     """Remove ``drop``-masked entries from sorted bounded lists.
@@ -120,6 +161,7 @@ def knn_compact_blocked(
     count of dropped valid entries.
     """
     n, k = cur_dist.shape
+    tm = tm or block_rows(n, k, k)
     npad = ((n + tm - 1) // tm) * tm
     pad = npad - n
     cur_dist = jnp.pad(cur_dist, ((0, pad), (0, 0)), constant_values=jnp.inf)
@@ -148,6 +190,7 @@ def knn_compact_blocked(
             jax.ShapeDtypeStruct((npad, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="knn_compact",
     )(cur_dist, cur_idx, drop_i)
     return od[:n], oi[:n], rm[:n, 0]
 
@@ -159,11 +202,12 @@ def knn_merge_blocked(
     cand_dist: jax.Array,  # (n, c) f32
     cand_idx: jax.Array,   # (n, c) int32, -1 = invalid
     *,
-    tm: int = DEFAULT_TM,
+    tm: int | None = None,    # rows per block (None: from VMEM bytes)
     interpret: bool = False,
 ):
     n, k = cur_dist.shape
     c = cand_dist.shape[1]
+    tm = tm or block_rows(n, k, c)
     npad = ((n + tm - 1) // tm) * tm
     pad = npad - n
     cur_dist = jnp.pad(cur_dist, ((0, pad), (0, 0)), constant_values=jnp.inf)
@@ -192,6 +236,7 @@ def knn_merge_blocked(
             jax.ShapeDtypeStruct((npad, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="knn_merge",
     )(cur_dist, cur_idx, cand_dist, cand_idx)
     return od[:n], oi[:n], upd[:n, 0]
 
@@ -214,7 +259,7 @@ def knn_merge_rows_blocked(
     cand_dist: jax.Array,  # (f, c) f32
     cand_idx: jax.Array,   # (f, c) int32, -1 = invalid
     *,
-    tm: int = DEFAULT_TM,
+    tm: int | None = None,    # rows per block (None: from VMEM bytes)
     interpret: bool = False,
 ):
     """Merge candidates into the listed rows only (full arrays returned)."""
@@ -240,7 +285,7 @@ def knn_compact_rows_blocked(
     rows: jax.Array,       # (f,) unique row ids, -1 = padding
     drop: jax.Array,       # (f, k) bool — frontier-local entries to remove
     *,
-    tm: int = DEFAULT_TM,
+    tm: int | None = None,    # rows per block (None: from VMEM bytes)
     interpret: bool = False,
 ):
     """Drop masked entries from the listed rows only (full arrays returned)."""

@@ -40,26 +40,48 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
 
-DEFAULT_TQ = 128    # query rows per block
+
+def search_block_rows(n: int, w: int, dp: int, dtype) -> int:
+    """Query rows per block of a search-distance kernel, from its VMEM
+    bytes: the double-buffered (W, dp) candidate tile, query row and
+    (W,) vectors, plus two fp32 (W, dp) temporaries (the operand cast to
+    fp32 and the batched product)."""
+    io = (tiling.tile_bytes((w, dp), dtype) + tiling.row_bytes(dp, dtype)
+          + 3 * tiling.row_bytes(1, jnp.float32)
+          + 4 * tiling.row_bytes(w, jnp.float32))
+    temps = 2 * tiling.tile_bytes((w, dp), jnp.float32)
+    return tiling.rows_per_block(2 * io + temps, n, cap=128)
+
+
+def search_tile(q, q2, cg, c2, ids, scale=None):
+    """Masked sq-l2 of one query block against its gathered candidates:
+    (TQ, dp) x (TQ, W, dp) -> (TQ, W), shared by the fp32, bf16 and int8
+    kernels. Operands are cast to fp32 first — exact for bf16 and int8
+    values, and Mosaic contracts a batched (TQ, W, dp) x (TQ, dp) pair
+    only in 32-bit. fp32 operands contract at ``Precision.HIGHEST``
+    (Mosaic's default rounds them to bf16 passes); bf16 and int8 values
+    are exact in one pass. ``scale`` (TQ, W) multiplies the cross terms
+    (the int8 dequant scales)."""
+    exact = cg.dtype == jnp.float32
+    ab = jax.lax.dot_general(
+        cg.astype(jnp.float32), q.astype(jnp.float32),
+        (((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST if exact else None,
+        preferred_element_type=jnp.float32,
+    )                                         # (TQ, W)
+    if scale is not None:
+        ab = scale * ab
+    dd = q2 + c2 - 2.0 * ab
+    return jnp.where(ids >= 0, jnp.maximum(dd, 0.0), jnp.inf)
 
 
 def _search_dists_kernel(q_ref, q2_ref, cg_ref, c2_ref, ids_ref, od_ref):
     """Candidate distance tile for one query block: (TQ, dp) queries x
     (TQ, W, dp) gathered candidate features -> (TQ, W) masked sq-l2."""
-    q = q_ref[...].astype(jnp.float32)        # (TQ, dp)
-    q2 = q2_ref[...]                          # (TQ, 1)
-    cg = cg_ref[...].astype(jnp.float32)      # (TQ, W, dp)
-    c2 = c2_ref[...]                          # (TQ, W)
-    ids = ids_ref[...]                        # (TQ, W), -1 = invalid/dead
-
-    # cross terms on the MXU (batched over the query block), fp32 accum
-    ab = jax.lax.dot_general(
-        cg, q, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                         # (TQ, W)
-    dd = q2 + c2 - 2.0 * ab
-    od_ref[...] = jnp.where(ids >= 0, jnp.maximum(dd, 0.0), jnp.inf)
+    od_ref[...] = search_tile(q_ref[...], q2_ref[...], cg_ref[...],
+                              c2_ref[...], ids_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "interpret"))
@@ -70,7 +92,7 @@ def knn_search_dists_blocked(
     c2g: jax.Array,    # (nq, W) cached candidate squared norms
     ids: jax.Array,    # (nq, W) candidate ids, -1 = invalid (incl. dead)
     *,
-    tq: int = DEFAULT_TQ,
+    tq: int | None = None,   # query rows per block (None: VMEM bytes)
     interpret: bool = False,
 ):
     """Blocked query-time candidate distances.
@@ -79,27 +101,36 @@ def knn_search_dists_blocked(
     (including tombstone/alive masking) is encoded by the caller as
     ``ids == -1`` and applied in the kernel epilogue.
     """
+    return search_call(_search_dists_kernel, "knn_search_dists", [q, q2],
+                       cg, [c2g], ids, tq, interpret)
+
+
+def search_call(kernel, name, q_rows, cg, c_rows, ids, tq, interpret):
+    """pallas_call of a search-distance kernel (fp32 here, bf16/int8 in
+    kernels/l2_quant.py): ``q_rows`` are the (nq, dp) query rows then
+    (nq,) per-query vectors, ``cg`` the (nq, W, dp) gathered candidates,
+    ``c_rows`` (nq, W) per-candidate vectors, ``ids`` (nq, W)."""
     nq, w, dp = cg.shape
+    tq = tq or search_block_rows(nq, w, dp, cg.dtype)
     npad = ((nq + tq - 1) // tq) * tq
     pad = npad - nq
+    q, *qv = q_rows
     q = jnp.pad(q, ((0, pad), (0, 0)))
-    q2 = jnp.pad(q2, (0, pad))
+    qv = [jnp.pad(v, (0, pad))[:, None] for v in qv]
     cg = jnp.pad(cg, ((0, pad), (0, 0), (0, 0)))
-    c2g = jnp.pad(c2g, ((0, pad), (0, 0)))
+    c_rows = [jnp.pad(v, ((0, pad), (0, 0))) for v in c_rows]
     ids = jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1)
-
-    od = pl.pallas_call(
-        _search_dists_kernel,
+    col = pl.BlockSpec((tq, 1), lambda i: (i, 0))
+    row = pl.BlockSpec((tq, w), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
         grid=(npad // tq,),
-        in_specs=[
-            pl.BlockSpec((tq, dp), lambda i: (i, 0)),
-            pl.BlockSpec((tq, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tq, w, dp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tq, w), lambda i: (i, 0)),
-            pl.BlockSpec((tq, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((tq, w), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((tq, dp), lambda i: (i, 0))]
+        + [col] * len(qv)
+        + [pl.BlockSpec((tq, w, dp), lambda i: (i, 0, 0))]
+        + [row] * (len(c_rows) + 1),
+        out_specs=row,
         out_shape=jax.ShapeDtypeStruct((npad, w), jnp.float32),
         interpret=interpret,
-    )(q, q2[:, None], cg, c2g, ids)
-    return od[:nq]
+        name=name,
+    )(q, *qv, cg, *c_rows, ids)[:nq]

@@ -46,9 +46,11 @@ def _l2_kernel(a_ref, b_ref, out_ref, acc_ref, a2_ref, b2_ref):
 
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
-    # cross term on the MXU, fp32 accumulation
+    # cross term on the MXU at full fp32 precision
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     a2_ref[...] += jnp.sum(a * a, axis=1, keepdims=True)
     b2_ref[...] += jnp.sum(b * b, axis=1, keepdims=True).T
@@ -97,6 +99,7 @@ def pairwise_sq_l2_blocked(
             pltpu.VMEM((1, tn), jnp.float32),
         ],
         interpret=interpret,
+        name="pairwise_sq_l2",
     )(a, b)
     return out[:m, :n]
 

@@ -7,26 +7,31 @@ and kernels/knn_join.py (build: (TB, C, C) pair tensor per row block).
 What changes is the operand feed and the epilogue:
 
   * **int8** — rows arrive as symmetric per-row int8 with fp32 dequant
-    scales (core/quantize.py). The cross terms run int8 x int8 on the MXU
-    with int32 accumulation (`preferred_element_type=jnp.int32` — the
-    native int8 systolic path, 4x the fp32 arithmetic density and 1/4 the
-    HBM bytes per row), and the scale application is FUSED into the
-    epilogue together with the norm expansion:
+    scales (core/quantize.py), 1/4 the HBM bytes per row. The build's
+    pair tensors run int8 x int8 on the MXU with int32 accumulation
+    (`preferred_element_type=jnp.int32`, the native int8 systolic path);
+    the serving tile widens the rows to fp32 in VMEM first (Mosaic
+    contracts its batched (TQ, W, dp) x (TQ, dp) form only in 32-bit;
+    integer products and their sums are exact in fp32 for dp <= 1040).
+    The scale application is FUSED into the epilogue together with the
+    norm expansion:
 
         d(a, b) = ||a||^2 + ||b||^2 - 2 * s_a * s_b * (a_i8 . b_i8)
 
     with ||.||^2 the cached norms of the QUANTIZED rows, so d(a, a) == 0
     exactly and near-identical rows cannot cancel below the clamp.
 
-  * **bf16** — rows arrive as bf16 and feed the MXU directly (no scales,
-    2x density / half the bytes); accumulation stays fp32.
+  * **bf16** — rows arrive as bf16 (no scales, half the bytes); the
+    build's pair tensors feed the MXU directly, the serving tile widens
+    them in VMEM like int8. Accumulation stays fp32.
 
 Every output is fp32 with +inf on masked entries, exactly like the fp32
 kernels, so the downstream select/merge machinery is unchanged — only
-the scoring dtype moved. The fp32 kernels remain the RE-RANK stage: the
-two-stage drivers (core/graph_search.py, core/nn_descent.py) re-score
-surviving candidates with them before returning, so quantization shows
-up as bounded candidate-recall noise, never as a wrong distance.
+the scoring dtype moved. Returned distances never come from here: the
+two-stage callers (core/graph_search.py, core/nn_descent.py) re-rank
+surviving candidates in exact fp32 difference form before returning,
+so quantization shows up as bounded candidate-recall noise, never as a
+wrong distance.
 
 ref.py holds pure-jnp oracles. They accumulate the int8 cross terms in
 fp32 (the fast CPU path: integer products are exact in fp32 while the
@@ -39,11 +44,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-DEFAULT_TQ = 128    # query rows per block (search tiles)
-DEFAULT_TB = 128    # rows per block (join pair tensors)
+from repro.kernels.knn_join import join_call, join_rows
+from repro.kernels.knn_search import search_call, search_tile
 
 
 # ---------------------------------------------------------------------------
@@ -54,38 +57,17 @@ DEFAULT_TB = 128    # rows per block (join pair tensors)
 def _search_dists_q8_kernel(qq_ref, qs_ref, q2_ref, cq_ref, cs_ref, c2_ref,
                             ids_ref, od_ref):
     """int8 candidate tile: (TQ, dp) int8 queries x (TQ, W, dp) int8
-    gathered candidates -> (TQ, W) masked sq-l2 via int32 MXU accumulation
-    with the dequant scales applied in the epilogue."""
-    qq = qq_ref[...]                          # (TQ, dp) int8
-    qs = qs_ref[...]                          # (TQ, 1)
-    q2 = q2_ref[...]                          # (TQ, 1)
-    cq = cq_ref[...]                          # (TQ, W, dp) int8
-    cs = cs_ref[...]                          # (TQ, W)
-    c2 = c2_ref[...]                          # (TQ, W)
-    ids = ids_ref[...]                        # (TQ, W), -1 = invalid/dead
-
-    ab = jax.lax.dot_general(
-        cq, qq, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32,
-    )                                         # (TQ, W) i32
-    dd = q2 + c2 - 2.0 * (qs * cs) * ab.astype(jnp.float32)
-    od_ref[...] = jnp.where(ids >= 0, jnp.maximum(dd, 0.0), jnp.inf)
+    gathered candidates -> (TQ, W) masked sq-l2, with the dequant scales
+    applied to the (exact) integer cross terms in the epilogue."""
+    od_ref[...] = search_tile(qq_ref[...], q2_ref[...], cq_ref[...],
+                              c2_ref[...], ids_ref[...],
+                              scale=qs_ref[...] * cs_ref[...])
 
 
 def _search_dists_bf16_kernel(q_ref, q2_ref, cg_ref, c2_ref, ids_ref, od_ref):
-    """bf16 candidate tile: operands stay bf16 into the MXU, fp32 accum."""
-    q = q_ref[...]                            # (TQ, dp) bf16
-    q2 = q2_ref[...]                          # (TQ, 1)
-    cg = cg_ref[...]                          # (TQ, W, dp) bf16
-    c2 = c2_ref[...]                          # (TQ, W)
-    ids = ids_ref[...]                        # (TQ, W)
-
-    ab = jax.lax.dot_general(
-        cg, q, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                         # (TQ, W)
-    dd = q2 + c2 - 2.0 * ab
-    od_ref[...] = jnp.where(ids >= 0, jnp.maximum(dd, 0.0), jnp.inf)
+    """bf16 candidate tile: bf16 rows in VMEM, fp32 accumulation."""
+    od_ref[...] = search_tile(q_ref[...], q2_ref[...], cg_ref[...],
+                              c2_ref[...], ids_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "interpret"))
@@ -98,39 +80,14 @@ def knn_search_dists_q8_blocked(
     c2g: jax.Array,    # (nq, W) cached quantized-candidate squared norms
     ids: jax.Array,    # (nq, W) candidate ids, -1 = invalid (incl. dead)
     *,
-    tq: int = DEFAULT_TQ,
+    tq: int | None = None,   # query rows per block (None: VMEM bytes)
     interpret: bool = False,
 ):
     """Blocked int8 query-time candidate distances (see module docstring).
     Returns dists (nq, W) f32 with +inf on invalid candidates."""
-    nq, w, dp = cq.shape
-    npad = ((nq + tq - 1) // tq) * tq
-    pad = npad - nq
-    qq = jnp.pad(qq, ((0, pad), (0, 0)))
-    qscale = jnp.pad(qscale, (0, pad))
-    q2 = jnp.pad(q2, (0, pad))
-    cq = jnp.pad(cq, ((0, pad), (0, 0), (0, 0)))
-    cscale = jnp.pad(cscale, ((0, pad), (0, 0)))
-    c2g = jnp.pad(c2g, ((0, pad), (0, 0)))
-    ids = jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1)
-
-    od = pl.pallas_call(
-        _search_dists_q8_kernel,
-        grid=(npad // tq,),
-        in_specs=[
-            pl.BlockSpec((tq, dp), lambda i: (i, 0)),
-            pl.BlockSpec((tq, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tq, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tq, w, dp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tq, w), lambda i: (i, 0)),
-            pl.BlockSpec((tq, w), lambda i: (i, 0)),
-            pl.BlockSpec((tq, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((tq, w), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((npad, w), jnp.float32),
-        interpret=interpret,
-    )(qq, qscale[:, None], q2[:, None], cq, cscale, c2g, ids)
-    return od[:nq]
+    return search_call(_search_dists_q8_kernel, "knn_search_dists_q8",
+                        [qq, qscale, q2], cq, [cscale, c2g], ids, tq,
+                        interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "interpret"))
@@ -141,35 +98,13 @@ def knn_search_dists_bf16_blocked(
     c2g: jax.Array,    # (nq, W) cached bf16-candidate squared norms
     ids: jax.Array,    # (nq, W) candidate ids, -1 = invalid (incl. dead)
     *,
-    tq: int = DEFAULT_TQ,
+    tq: int | None = None,   # query rows per block (None: VMEM bytes)
     interpret: bool = False,
 ):
     """Blocked bf16 query-time candidate distances. Same contract as
     knn_search_dists_q8_blocked minus the scales."""
-    nq, w, dp = cg.shape
-    npad = ((nq + tq - 1) // tq) * tq
-    pad = npad - nq
-    q = jnp.pad(q, ((0, pad), (0, 0)))
-    q2 = jnp.pad(q2, (0, pad))
-    cg = jnp.pad(cg, ((0, pad), (0, 0), (0, 0)))
-    c2g = jnp.pad(c2g, ((0, pad), (0, 0)))
-    ids = jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1)
-
-    od = pl.pallas_call(
-        _search_dists_bf16_kernel,
-        grid=(npad // tq,),
-        in_specs=[
-            pl.BlockSpec((tq, dp), lambda i: (i, 0)),
-            pl.BlockSpec((tq, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tq, w, dp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tq, w), lambda i: (i, 0)),
-            pl.BlockSpec((tq, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((tq, w), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((npad, w), jnp.float32),
-        interpret=interpret,
-    )(q, q2[:, None], cg, c2g, ids)
-    return od[:nq]
+    return search_call(_search_dists_bf16_kernel, "knn_search_dists_bf16",
+                        [q, q2], cg, [c2g], ids, tq, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -177,64 +112,31 @@ def knn_search_dists_bf16_blocked(
 # ---------------------------------------------------------------------------
 
 
-def _join_mask(ids: jax.Array, cn: int):
-    """Join validity for one row block (same rule as kernels/knn_join.py):
-    at least one endpoint "new", distinct slots, both occupied, distinct
-    node ids. ids: (TB, C) -> ok (TB, C, C)."""
-    c = ids.shape[1]
-    slot_s = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)[None]
-    slot_t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)[None]
-    ok = (slot_s < cn) | (slot_t < cn)
-    ok &= slot_s != slot_t
-    ok &= (ids[:, :, None] >= 0) & (ids[:, None, :] >= 0)
-    ok &= ids[:, :, None] != ids[:, None, :]
-    return ok
-
-
-def _join_dists_q8_kernel(xq_ref, xs_ref, x2_ref, ids_ref, od_ref, ev_ref,
+def _join_dists_q8_kernel(xq_ref, x2_ref, xs_ref, ids_ref, od_ref, ev_ref,
                           *, cn: int):
     """int8 pair tensor for one row block: (TB, C, dp) int8 gathered
     candidates -> (TB, C, C) masked sq-l2, int32 MXU accumulation."""
-    xq = xq_ref[...]                          # (TB, C, dp) int8
-    xs = xs_ref[...]                          # (TB, C)
-    x2 = x2_ref[...]                          # (TB, C)
-    ids = ids_ref[...]                        # (TB, C)
+    def cross(r):
+        x = xq_ref[r]                         # (C, dp) int8
+        return jax.lax.dot_general(
+            x, x, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        ).astype(jnp.float32)
 
-    ab = jax.lax.dot_general(
-        xq, xq, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32,
-    )                                         # (TB, C, C) i32
-    dd = x2[:, :, None] + x2[:, None, :] - 2.0 * (
-        xs[:, :, None] * xs[:, None, :]
-    ) * ab.astype(jnp.float32)
-    ok = _join_mask(ids, cn)
-    od_ref[...] = jnp.where(ok, jnp.maximum(dd, 0.0), jnp.inf)
-    ev_ref[...] = (jnp.sum(ok.astype(jnp.int32), axis=(1, 2)) // 2)[:, None]
+    join_rows(cross, x2_ref, ids_ref, od_ref, ev_ref, cn=cn, xs_ref=xs_ref)
 
 
 def _join_dists_bf16_kernel(xg_ref, x2_ref, ids_ref, od_ref, ev_ref,
                             *, cn: int):
     """bf16 pair tensor for one row block, fp32 accumulation."""
-    xg = xg_ref[...]                          # (TB, C, dp) bf16
-    x2 = x2_ref[...]                          # (TB, C)
-    ids = ids_ref[...]                        # (TB, C)
+    def cross(r):
+        x = xg_ref[r]                         # (C, dp) bf16
+        return jax.lax.dot_general(
+            x, x, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    ab = jax.lax.dot_general(
-        xg, xg, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                         # (TB, C, C)
-    dd = x2[:, :, None] + x2[:, None, :] - 2.0 * ab
-    ok = _join_mask(ids, cn)
-    od_ref[...] = jnp.where(ok, jnp.maximum(dd, 0.0), jnp.inf)
-    ev_ref[...] = (jnp.sum(ok.astype(jnp.int32), axis=(1, 2)) // 2)[:, None]
-
-
-def _pad_join(arrs, ids, tb):
-    n = ids.shape[0]
-    npad = ((n + tb - 1) // tb) * tb
-    pad = npad - n
-    out = [jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)) for a in arrs]
-    return out, jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1), npad
+    join_rows(cross, x2_ref, ids_ref, od_ref, ev_ref, cn=cn)
 
 
 @functools.partial(jax.jit, static_argnames=("cn", "tb", "interpret"))
@@ -245,34 +147,13 @@ def knn_join_dists_q8_blocked(
     ids: jax.Array,    # (n, C) candidate node ids, -1 = invalid slot
     *,
     cn: int,           # width of the "new" candidate prefix
-    tb: int = DEFAULT_TB,
+    tb: int | None = None,   # rows per block (None: from VMEM bytes)
     interpret: bool = False,
 ):
     """Blocked int8 local-join pair distances. Returns (dists (n, C, C)
     f32 with +inf on invalid pairs, evals (n,) int32)."""
-    n, c, dp = xq.shape
-    (xq, xscale, x2g), ids, npad = _pad_join([xq, xscale, x2g], ids, tb)
-    kern = functools.partial(_join_dists_q8_kernel, cn=cn)
-    od, ev = pl.pallas_call(
-        kern,
-        grid=(npad // tb,),
-        in_specs=[
-            pl.BlockSpec((tb, c, dp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, c), lambda i: (i, 0)),
-            pl.BlockSpec((tb, c), lambda i: (i, 0)),
-            pl.BlockSpec((tb, c), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, c, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((npad, c, c), jnp.float32),
-            jax.ShapeDtypeStruct((npad, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xq, xscale, x2g, ids)
-    return od[:n], ev[:n, 0]
+    return join_call(_join_dists_q8_kernel, "knn_join_dists_q8", xq,
+                     [x2g, xscale], ids, cn=cn, tb=tb, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("cn", "tb", "interpret"))
@@ -282,30 +163,10 @@ def knn_join_dists_bf16_blocked(
     ids: jax.Array,    # (n, C) candidate node ids, -1 = invalid slot
     *,
     cn: int,
-    tb: int = DEFAULT_TB,
+    tb: int | None = None,   # rows per block (None: from VMEM bytes)
     interpret: bool = False,
 ):
     """Blocked bf16 local-join pair distances. Same contract as the int8
     form minus the scales."""
-    n, c, dp = xg.shape
-    (xg, x2g), ids, npad = _pad_join([xg, x2g], ids, tb)
-    kern = functools.partial(_join_dists_bf16_kernel, cn=cn)
-    od, ev = pl.pallas_call(
-        kern,
-        grid=(npad // tb,),
-        in_specs=[
-            pl.BlockSpec((tb, c, dp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, c), lambda i: (i, 0)),
-            pl.BlockSpec((tb, c), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, c, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((npad, c, c), jnp.float32),
-            jax.ShapeDtypeStruct((npad, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xg, x2g, ids)
-    return od[:n], ev[:n, 0]
+    return join_call(_join_dists_bf16_kernel, "knn_join_dists_bf16", xg,
+                     [x2g], ids, cn=cn, tb=tb, interpret=interpret)

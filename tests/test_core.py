@@ -209,3 +209,15 @@ def test_merge_keeps_sorted_and_counts():
     d = np.asarray(out.dist[0])
     assert (np.diff(d) >= 0).all()
     assert np.asarray(out.idx[0]).tolist() == [7, 3, 5]
+
+
+# ---------------------------------------------------------------------------
+# row-chunked layout helpers (layout.py)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,r", [((2500, 20), 1024), ((700, 3, 5), 256),
+                                     ((300, 1), 64), ((40, 7), 1024)])
+def test_flatten_rows_matches_reshape(shape, r):
+    from repro.core.layout import flatten_rows
+    a = jnp.arange(np.prod(shape), dtype=jnp.int32).reshape(shape)
+    np.testing.assert_array_equal(flatten_rows(a, r), a.reshape(-1))

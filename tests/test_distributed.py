@@ -259,3 +259,20 @@ def test_graph_search_sharded_routed_parity_and_fanout():
         print('routed', float(r_rt), float(r_rep), float(inter), st)
     """)
     assert "routed" in out
+
+
+def test_bucket_slots_rank_rows_per_destination():
+    # a row's slot is its rank among the rows bound for the same shard,
+    # in row order; rows with no destination (== P) get a slot past
+    # every bucket, so the routed scatters drop them
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.distributed import _bucket_slots
+
+    dest = np.random.default_rng(0).integers(0, 5, 1000).astype(np.int32)
+    pos = np.asarray(_bucket_slots(jnp.asarray(dest), 4))
+    for p in range(4):
+        np.testing.assert_array_equal(pos[dest == p],
+                                      np.arange((dest == p).sum()))
+    assert (pos[dest == 4] == dest.size).all()
