@@ -82,6 +82,7 @@ from repro.core import heap, quantize
 from repro.core import metric as metric_mod
 from repro.core.heap import NeighborLists
 from repro.core.quantize import QuantizedStore
+from repro.core.spans import span
 from repro.kernels import ops
 
 
@@ -376,7 +377,8 @@ def graph_search(
                                              - queries.shape[1])))
     else:
         metric_mod.check_metric(cfg.metric)
-    queries, bad_rows = _admit_queries(queries, x.shape[1], cfg.strict)
+    with span("search.admit", rows=queries.shape[0]):
+        queries, bad_rows = _admit_queries(queries, x.shape[1], cfg.strict)
     n = graph_idx.shape[0]
     filt = None
     if filter_ids is not None:
@@ -400,10 +402,10 @@ def graph_search(
     if x2 is None:
         x2 = jnp.sum(x * x, axis=1)
     if entry is None:
-        key = _batch_key(queries) if key is None else key
-        if (router is not None and cfg.router != "off"
-                and cfg.backend != "ref" and queries.shape[0] > 0):
-            from repro.core.router import route_entries
+        routed = (router is not None and cfg.router != "off"
+                  and cfg.backend != "ref" and queries.shape[0] > 0)
+        width = min(cfg.beam, n)
+        if routed:
             # probe the FULL member set of the top-t centroids (IVF-style:
             # up to t*m candidates), not just beam of them — the seed tile
             # scores every candidate against the query and the bounded
@@ -411,18 +413,23 @@ def graph_search(
             # wider seed tile, never a wider traversal
             t = min(cfg.router_t, router.centroids.shape[0])
             width = min(max(cfg.beam, t * router.members.idx.shape[1]), n)
-            ent = route_entries(
-                router, queries, width, t=cfg.router_t, backend=cfg.backend,
-            )                                           # (q, e), -1 holes
-            if alive is not None:
-                ent = jnp.where(
-                    (ent >= 0) & alive[jnp.clip(ent, 0, n - 1)], ent, -1
-                )
-            # holes (dead or missing members) fall back to a random draw
-            rnd = _draw_entries(key, n, width, alive)
-            entry = jnp.where(ent >= 0, ent, rnd[None, :])
-        else:
-            entry = _draw_entries(key, n, cfg.beam, alive)
+        with span("search.route", width=width):
+            key = _batch_key(queries) if key is None else key
+            if routed:
+                from repro.core.router import route_entries
+                ent = route_entries(
+                    router, queries, width, t=cfg.router_t,
+                    backend=cfg.backend,
+                )                                       # (q, e), -1 holes
+                if alive is not None:
+                    ent = jnp.where(
+                        (ent >= 0) & alive[jnp.clip(ent, 0, n - 1)], ent, -1
+                    )
+                # holes (dead or missing members) fall back to a random draw
+                rnd = _draw_entries(key, n, width, alive)
+                entry = jnp.where(ent >= 0, ent, rnd[None, :])
+            else:
+                entry = _draw_entries(key, n, cfg.beam, alive)
     entry = entry.astype(jnp.int32)
     if filt is not None:
         # per-query predicates need per-query entries: broadcast shared
@@ -473,51 +480,52 @@ def graph_search(
                 jnp.full((0, k_out), -1, jnp.int32))
     qb = q_block_bucket(nq, cfg)
     pad = (-nq) % qb
-    qp = jnp.pad(queries, ((0, pad), (0, 0)))
-    q2 = jnp.sum(qp * qp, axis=1)
-    if entry.ndim == 2:     # per-query seeds ride along with their block
-        entry = jnp.pad(entry, ((0, pad), (0, 0)), constant_values=-1)
-    if filt is not None:    # pad queries admit everything (sliced off)
-        filt = jnp.pad(filt, ((0, pad), (0, 0)), constant_values=True)
-    # Deadline degradation: once the batch has spent its cumulative
-    # per-block slice, remaining blocks run with the expansion budget cut
-    # to ONE fused round — the answer degrades (fewer expansions, lower
-    # recall), the latency does not. Needs wall time, so each block is
-    # synced when armed; meaningless under tracing (no wall clock), so
-    # the knob is ignored there.
-    deadline = cfg.max_rounds_deadline
-    use_deadline = deadline > 0.0 and not isinstance(queries,
-                                                    jax.core.Tracer)
-    # host-side knobs (the deadline value, the fixed_block baseline flag)
-    # must not fragment _search_block's static-cfg compile cache: the
-    # scheduler propagates a FRESH max_rounds_deadline per dispatch, and
-    # keying compiles on it would recompile every batch for an identical
-    # traced computation
-    run_cfg = dataclasses.replace(cfg, max_rounds_deadline=0.0,
-                                  fixed_block=False)
-    cut_cfg = None
-    t0 = time.monotonic() if use_deadline else 0.0
-    outs_d, outs_i = [], []
-    for bi, s in enumerate(range(0, nq + pad, qb)):
-        bcfg = run_cfg
-        if use_deadline and bi > 0 \
-                and time.monotonic() - t0 > deadline * bi:
-            if cut_cfg is None:     # one extra (cached) compile, ever
-                cut_cfg = dataclasses.replace(run_cfg, rounds=cfg.expand)
-            bcfg = cut_cfg
-        ent_b = entry if entry.ndim == 1 else entry[s:s + qb]
-        od, oi = _search_block(
-            x, x2, graph_idx, qp[s:s + qb], q2[s:s + qb], ent_b, alive,
-            None if filt is None else filt[s:s + qb],
-            qstore, k_out=k_out, cfg=bcfg,
-        )
-        if use_deadline:
-            od.block_until_ready()
-        outs_d.append(od)
-        outs_i.append(oi)
-    out_d = outs_d[0] if len(outs_d) == 1 else jnp.concatenate(outs_d)
-    out_i = outs_i[0] if len(outs_i) == 1 else jnp.concatenate(outs_i)
-    return _mask_bad_rows(out_d[:nq], out_i[:nq], bad_rows)
+    with span("search.dispatch", blocks=(nq + pad) // qb, q_block=qb):
+        qp = jnp.pad(queries, ((0, pad), (0, 0)))
+        q2 = jnp.sum(qp * qp, axis=1)
+        if entry.ndim == 2:     # per-query seeds ride along with their block
+            entry = jnp.pad(entry, ((0, pad), (0, 0)), constant_values=-1)
+        if filt is not None:    # pad queries admit everything (sliced off)
+            filt = jnp.pad(filt, ((0, pad), (0, 0)), constant_values=True)
+        # Deadline degradation: once the batch has spent its cumulative
+        # per-block slice, remaining blocks run with the expansion budget cut
+        # to ONE fused round — the answer degrades (fewer expansions, lower
+        # recall), the latency does not. Needs wall time, so each block is
+        # synced when armed; meaningless under tracing (no wall clock), so
+        # the knob is ignored there.
+        deadline = cfg.max_rounds_deadline
+        use_deadline = deadline > 0.0 and not isinstance(queries,
+                                                        jax.core.Tracer)
+        # host-side knobs (the deadline value, the fixed_block baseline flag)
+        # must not fragment _search_block's static-cfg compile cache: the
+        # scheduler propagates a FRESH max_rounds_deadline per dispatch, and
+        # keying compiles on it would recompile every batch for an identical
+        # traced computation
+        run_cfg = dataclasses.replace(cfg, max_rounds_deadline=0.0,
+                                      fixed_block=False)
+        cut_cfg = None
+        t0 = time.monotonic() if use_deadline else 0.0
+        outs_d, outs_i = [], []
+        for bi, s in enumerate(range(0, nq + pad, qb)):
+            bcfg = run_cfg
+            if use_deadline and bi > 0 \
+                    and time.monotonic() - t0 > deadline * bi:
+                if cut_cfg is None:     # one extra (cached) compile, ever
+                    cut_cfg = dataclasses.replace(run_cfg, rounds=cfg.expand)
+                bcfg = cut_cfg
+            ent_b = entry if entry.ndim == 1 else entry[s:s + qb]
+            od, oi = _search_block(
+                x, x2, graph_idx, qp[s:s + qb], q2[s:s + qb], ent_b, alive,
+                None if filt is None else filt[s:s + qb],
+                qstore, k_out=k_out, cfg=bcfg,
+            )
+            if use_deadline:
+                od.block_until_ready()
+            outs_d.append(od)
+            outs_i.append(oi)
+        out_d = outs_d[0] if len(outs_d) == 1 else jnp.concatenate(outs_d)
+        out_i = outs_i[0] if len(outs_i) == 1 else jnp.concatenate(outs_i)
+        return _mask_bad_rows(out_d[:nq], out_i[:nq], bad_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -558,65 +566,68 @@ def _search_block(
     # self-consistent; the exact fp32 re-rank of the final pool happens
     # after the round loop
     quant = cfg.precision != "f32" and qstore is not None
-    if quant:
-        qq = quantize.quantize_corpus(q, cfg.precision,
-                                      width=qstore.data.shape[1])
-
-    # ---- seed the pool: all entry distances in ONE blocked matmul, then
-    # one bounded merge (dedups repeated entries, drops dead ones)
-    ent = jnp.clip(entry, 0, n - 1)
-    if entry.ndim == 2:
-        # per-query (routed) seeds: the gathered (qb, e, dp) rows go
-        # through the same masked search tile as candidate scoring, so
-        # -1 holes come back +inf and vanish in the merge
-        eids = entry
-        if alive is not None:
-            eids = jnp.where(alive[ent], eids, -1)
-        if filt is not None:   # filt implies per-query entries (dispatcher)
-            eids = jnp.where(jnp.take_along_axis(filt, ent, 1), eids, -1)
+    # the phases run under named scopes (spans.SCOPES): the chip trace
+    # carries each in its ops' ``tf_op``, and the benchmark adds up
+    # device time per phase from it
+    with jax.named_scope("seed"):
         if quant:
-            c2q = jnp.where(eids >= 0, qstore.x2[ent], 0.0)
-            if cfg.precision == "int8":
-                ed = ops.knn_search_dists_q8(
-                    qq.data, qq.scale, qq.x2, qstore.data[ent],
-                    qstore.scale[ent], c2q, eids, backend=cfg.backend,
-                )                                       # (qb, E0)
+            qq = quantize.quantize_corpus(q, cfg.precision,
+                                          width=qstore.data.shape[1])
+        # ---- seed the pool: all entry distances in ONE blocked matmul, then
+        # one bounded merge (dedups repeated entries, drops dead ones)
+        ent = jnp.clip(entry, 0, n - 1)
+        if entry.ndim == 2:
+            # per-query (routed) seeds: the gathered (qb, e, dp) rows go
+            # through the same masked search tile as candidate scoring, so
+            # -1 holes come back +inf and vanish in the merge
+            eids = entry
+            if alive is not None:
+                eids = jnp.where(alive[ent], eids, -1)
+            if filt is not None:   # filt implies per-query entries (dispatcher)
+                eids = jnp.where(jnp.take_along_axis(filt, ent, 1), eids, -1)
+            if quant:
+                c2q = jnp.where(eids >= 0, qstore.x2[ent], 0.0)
+                if cfg.precision == "int8":
+                    ed = ops.knn_search_dists_q8(
+                        qq.data, qq.scale, qq.x2, qstore.data[ent],
+                        qstore.scale[ent], c2q, eids, backend=cfg.backend,
+                    )                                       # (qb, E0)
+                else:
+                    ed = ops.knn_search_dists_bf16(
+                        qq.data, qq.x2, qstore.data[ent], c2q, eids,
+                        backend=cfg.backend,
+                    )                                       # (qb, E0)
             else:
-                ed = ops.knn_search_dists_bf16(
-                    qq.data, qq.x2, qstore.data[ent], c2q, eids,
+                ed = ops.knn_search_dists(
+                    q, q2, x[ent], jnp.where(eids >= 0, x2[ent], 0.0), eids,
                     backend=cfg.backend,
-                )                                       # (qb, E0)
+                )                                           # (qb, E0)
+        elif quant:
+            ab = qq.data.astype(jnp.float32) @ (
+                qstore.data[ent].astype(jnp.float32).T
+            )
+            ab = (qq.scale[:, None] * qstore.scale[ent][None, :]) * ab
+            ed = jnp.maximum(
+                qq.x2[:, None] + qstore.x2[ent][None, :] - 2.0 * ab, 0.0
+            )                                               # (qb, E0)
+            eids = jnp.broadcast_to(entry[None, :], ed.shape)
+            if alive is not None:
+                eids = jnp.where(alive[ent][None, :], eids, -1)
         else:
-            ed = ops.knn_search_dists(
-                q, q2, x[ent], jnp.where(eids >= 0, x2[ent], 0.0), eids,
-                backend=cfg.backend,
-            )                                           # (qb, E0)
-    elif quant:
-        ab = qq.data.astype(jnp.float32) @ (
-            qstore.data[ent].astype(jnp.float32).T
+            ed = jnp.maximum(
+                q2[:, None] + x2[ent][None, :] - 2.0 * q @ x[ent].T, 0.0
+            )                                               # (qb, E0)
+            eids = jnp.broadcast_to(entry[None, :], ed.shape)
+            if alive is not None:
+                eids = jnp.where(alive[ent][None, :], eids, -1)
+        pool = NeighborLists(
+            jnp.full((qb, beam), jnp.inf, jnp.float32),
+            jnp.full((qb, beam), -1, jnp.int32),
+            jnp.zeros((qb, beam), bool),        # ``new`` == not yet expanded
         )
-        ab = (qq.scale[:, None] * qstore.scale[ent][None, :]) * ab
-        ed = jnp.maximum(
-            qq.x2[:, None] + qstore.x2[ent][None, :] - 2.0 * ab, 0.0
-        )                                               # (qb, E0)
-        eids = jnp.broadcast_to(entry[None, :], ed.shape)
-        if alive is not None:
-            eids = jnp.where(alive[ent][None, :], eids, -1)
-    else:
-        ed = jnp.maximum(
-            q2[:, None] + x2[ent][None, :] - 2.0 * q @ x[ent].T, 0.0
-        )                                               # (qb, E0)
-        eids = jnp.broadcast_to(entry[None, :], ed.shape)
-        if alive is not None:
-            eids = jnp.where(alive[ent][None, :], eids, -1)
-    pool = NeighborLists(
-        jnp.full((qb, beam), jnp.inf, jnp.float32),
-        jnp.full((qb, beam), -1, jnp.int32),
-        jnp.zeros((qb, beam), bool),        # ``new`` == not yet expanded
-    )
-    pool, _ = heap.merge_kernel(
-        pool, jnp.where(eids >= 0, ed, jnp.inf), eids, backend=cfg.backend
-    )
+        pool, _ = heap.merge_kernel(
+            pool, jnp.where(eids >= 0, ed, jnp.inf), eids, backend=cfg.backend
+        )
 
     inf_q = jnp.full((qb,), jnp.inf, jnp.float32)
     slot_iota = jnp.broadcast_to(
@@ -625,68 +636,81 @@ def _search_block(
 
     def round_fn(state):
         pool_d, pool_i, pool_new, r = state
-        # top-E unexpanded pool slots per query (partial top-C select —
-        # the same machinery as the build join, no full argsort)
-        _, ss = ops.knn_join_select(
-            pool_d, jnp.where(pool_new & (pool_i >= 0), slot_iota, -1),
-            inf_q, c=e, backend=cfg.backend,
-        )                                               # (qb, E) slots
-        can = ss >= 0
-        safe_s = jnp.where(can, ss, 0)
-        nodes = jnp.where(can, jnp.take_along_axis(pool_i, safe_s, 1), -1)
-        # mark expanded (disabled writes go out of bounds -> dropped)
-        pool_new = pool_new.at[rows, jnp.where(can, ss, beam)].set(
-            False, mode="drop"
-        )
-        # adjacency + feature gather for the whole block, then the fused
-        # distance tile with validity/alive masking in the epilogue
-        nbrs = graph_idx[jnp.clip(nodes, 0, n - 1)]     # (qb, E, k)
-        ok = can[:, :, None] & (nbrs >= 0)
-        if alive is not None:
-            ok &= alive[jnp.clip(nbrs, 0, n - 1)]
-        if filt is not None:
-            # per-query predicate: filtered candidates fold to -1 here,
-            # the epilogue maps id -1 to +inf — zero-leakage by the same
-            # mechanism tombstones use
-            ok &= jnp.take_along_axis(
-                filt, jnp.clip(nbrs, 0, n - 1).reshape(qb, e * k), 1
-            ).reshape(qb, e, k)
-        cand = jnp.where(ok, nbrs, -1).reshape(qb, e * k)
-        safe_c = jnp.where(cand >= 0, cand, 0)
-        if quant:
-            # quantized scoring tile: int8/bf16 gathered rows (2-4x fewer
-            # HBM bytes), scales + norm expansion fused in the epilogue
-            c2q = jnp.where(cand >= 0, qstore.x2[safe_c], 0.0)
-            if cfg.precision == "int8":
+        with jax.named_scope("select"):
+            # top-E unexpanded pool slots per query (partial top-C select
+            # — the same machinery as the build join, no full argsort)
+            _, ss = ops.knn_join_select(
+                pool_d, jnp.where(pool_new & (pool_i >= 0), slot_iota, -1),
+                inf_q, c=e, backend=cfg.backend,
+            )                                           # (qb, E) slots
+            can = ss >= 0
+            safe_s = jnp.where(can, ss, 0)
+            nodes = jnp.where(can, jnp.take_along_axis(pool_i, safe_s, 1),
+                              -1)
+            # mark expanded (disabled writes go out of bounds -> dropped)
+            pool_new = pool_new.at[rows, jnp.where(can, ss, beam)].set(
+                False, mode="drop"
+            )
+        with jax.named_scope("gather"):
+            # adjacency + feature gather for the whole block, then the
+            # fused distance tile with validity/alive masking in the
+            # epilogue
+            nbrs = graph_idx[jnp.clip(nodes, 0, n - 1)]     # (qb, E, k)
+            ok = can[:, :, None] & (nbrs >= 0)
+            if alive is not None:
+                ok &= alive[jnp.clip(nbrs, 0, n - 1)]
+            if filt is not None:
+                # per-query predicate: filtered candidates fold to -1
+                # here, the epilogue maps id -1 to +inf — zero-leakage by
+                # the same mechanism tombstones use
+                ok &= jnp.take_along_axis(
+                    filt, jnp.clip(nbrs, 0, n - 1).reshape(qb, e * k), 1
+                ).reshape(qb, e, k)
+            cand = jnp.where(ok, nbrs, -1).reshape(qb, e * k)
+            safe_c = jnp.where(cand >= 0, cand, 0)
+            if quant:
+                # quantized scoring tile: int8/bf16 gathered rows (2-4x
+                # fewer HBM bytes), scales + norm expansion fused in the
+                # epilogue
+                rows_c = qstore.data[safe_c]
+                scale_c = qstore.scale[safe_c] \
+                    if cfg.precision == "int8" else None
+                c2 = jnp.where(cand >= 0, qstore.x2[safe_c], 0.0)
+            else:
+                rows_c = x[safe_c]
+                c2 = jnp.where(cand >= 0, x2[safe_c], 0.0)
+        with jax.named_scope("score"):
+            if not quant:
+                dd = ops.knn_search_dists(
+                    q, q2, rows_c, c2, cand, backend=cfg.backend,
+                )                                       # (qb, E*k)
+            elif cfg.precision == "int8":
                 dd = ops.knn_search_dists_q8(
-                    qq.data, qq.scale, qq.x2, qstore.data[safe_c],
-                    qstore.scale[safe_c], c2q, cand, backend=cfg.backend,
+                    qq.data, qq.scale, qq.x2, rows_c, scale_c, c2, cand,
+                    backend=cfg.backend,
                 )                                       # (qb, E*k)
             else:
                 dd = ops.knn_search_dists_bf16(
-                    qq.data, qq.x2, qstore.data[safe_c], c2q, cand,
-                    backend=cfg.backend,
+                    qq.data, qq.x2, rows_c, c2, cand, backend=cfg.backend,
                 )                                       # (qb, E*k)
-        else:
-            dd = ops.knn_search_dists(
-                q, q2, x[safe_c], jnp.where(cand >= 0, x2[safe_c], 0.0),
-                cand, backend=cfg.backend,
-            )                                           # (qb, E*k)
-        # pool-k-th prefilter + partial top-C, then the sort-free bounded
-        # merge (dedup by id; accepted slots come back unexpanded)
-        cd, ci = ops.knn_join_select(
-            dd, cand, pool_d[:, -1], c=c_sel, backend=cfg.backend
-        )
-        nl, _ = heap.merge_kernel(
-            NeighborLists(pool_d, pool_i, pool_new), cd, ci,
-            backend=cfg.backend,
-        )
+        with jax.named_scope("merge"):
+            # pool-k-th prefilter + partial top-C, then the sort-free
+            # bounded merge (dedup by id; accepted slots come back
+            # unexpanded)
+            cd, ci = ops.knn_join_select(
+                dd, cand, pool_d[:, -1], c=c_sel, backend=cfg.backend
+            )
+            nl, _ = heap.merge_kernel(
+                NeighborLists(pool_d, pool_i, pool_new), cd, ci,
+                backend=cfg.backend,
+            )
         return nl.dist, nl.idx, nl.new, r + 1
 
     def cond_fn(state):
         pool_d, pool_i, pool_new, r = state
         # early-out: every pool entry of every query already expanded
-        return (r < cfg.n_rounds) & jnp.any(pool_new & (pool_i >= 0))
+        with jax.named_scope("select"):
+            return (r < cfg.n_rounds) & jnp.any(pool_new & (pool_i >= 0))
 
     pool_d, pool_i, _, _ = jax.lax.while_loop(
         cond_fn, round_fn,
@@ -697,11 +721,13 @@ def _search_block(
     # this is stage two — quantization decided pool membership (bounded
     # recall noise), never the returned distances/order; for fp32 it
     # replaces the traversal's norm-expansion values
-    dex = metric_mod.exact_sq_l2(q, x[jnp.clip(pool_i, 0, n - 1)], pool_i)
-    return ops.knn_join_select(
-        dex, pool_i, jnp.full((qb,), jnp.inf, jnp.float32), c=k_out,
-        backend=cfg.backend,
-    )
+    with jax.named_scope("rerank"):
+        dex = metric_mod.exact_sq_l2(q, x[jnp.clip(pool_i, 0, n - 1)],
+                                     pool_i)
+        return ops.knn_join_select(
+            dex, pool_i, jnp.full((qb,), jnp.inf, jnp.float32), c=k_out,
+            backend=cfg.backend,
+        )
 
 
 # ---------------------------------------------------------------------------

@@ -78,6 +78,7 @@ from repro.core.router import (
     router_delete,
     router_insert,
 )
+from repro.core.spans import span
 from repro.kernels import ops
 
 _FILL = 1e6   # coordinate fill for unallocated rows (cf. layout.pad_points)
@@ -335,28 +336,29 @@ class MutableKNNStore:
         the full capacity (shorter masks are False-padded: unallocated
         rows are inadmissible anyway) — filtered rows are never
         returned, exactly like tombstones."""
-        if cfg is None:
-            cfg = SearchConfig(
-                beam=beam, rounds=rounds, expand=self.cfg.seed_expand,
-                q_block=self.cfg.q_block, backend=self.cfg.backend,
-                precision=self.cfg.precision,
+        with span("store.search", queries=len(queries)):
+            if cfg is None:
+                cfg = SearchConfig(
+                    beam=beam, rounds=rounds, expand=self.cfg.seed_expand,
+                    q_block=self.cfg.q_block, backend=self.cfg.backend,
+                    precision=self.cfg.precision,
+                )
+            if cfg.metric != self.cfg.metric:
+                cfg = dataclasses.replace(cfg, metric=self.cfg.metric)
+            if filter_ids is not None:
+                filter_ids = jnp.asarray(filter_ids, bool)
+                short = self.capacity - filter_ids.shape[-1]
+                if short > 0:
+                    pad = [(0, 0)] * (filter_ids.ndim - 1) + [(0, short)]
+                    filter_ids = jnp.pad(filter_ids, pad,
+                                         constant_values=False)
+            q = _pad_to(metric_mod.transform_queries(queries, self.cfg.metric),
+                        self.x.shape[1])
+            return graph_search(
+                self.x, self.nl.idx, q, k_out=k_out, key=key,
+                alive=self.alive, x2=self.x2, cfg=cfg, qstore=self.qs,
+                router=self.router, filter_ids=filter_ids,
             )
-        if cfg.metric != self.cfg.metric:
-            cfg = dataclasses.replace(cfg, metric=self.cfg.metric)
-        if filter_ids is not None:
-            filter_ids = jnp.asarray(filter_ids, bool)
-            short = self.capacity - filter_ids.shape[-1]
-            if short > 0:
-                pad = [(0, 0)] * (filter_ids.ndim - 1) + [(0, short)]
-                filter_ids = jnp.pad(filter_ids, pad,
-                                     constant_values=False)
-        q = _pad_to(metric_mod.transform_queries(queries, self.cfg.metric),
-                    self.x.shape[1])
-        return graph_search(
-            self.x, self.nl.idx, q, k_out=k_out, key=key,
-            alive=self.alive, x2=self.x2, cfg=cfg, qstore=self.qs,
-            router=self.router, filter_ids=filter_ids,
-        )
 
 
 def _next_capacity(n: int) -> int:

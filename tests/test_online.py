@@ -396,3 +396,58 @@ def test_fully_tombstoned_store_insert_relinks(blob_split, base_store):
     got = np.asarray(idx)[:, 0]
     assert (got >= base_store.n).all()      # only the new rows surface
     assert (got == np.arange(xn.shape[0]) + base_store.n).all()
+
+
+def test_store_search_spans(tmp_path, blob_split, base_store):
+    """A CPU profile of one store search holds each span of
+    ``spans.SPANS`` once, with its host-integer arguments, nested as the
+    table says, all on the thread that called the search."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.core import spans
+    from repro.core.graph_search import SearchConfig
+    q = blob_split[1][:40]
+    cfg = SearchConfig(beam=16, rounds=16, q_block=32)
+    jax.block_until_ready(base_store.search(q, k_out=5, cfg=cfg))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(base_store.search(q, k_out=5, cfg=cfg))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(spans.PREFIX):
+                    found.setdefault(ev.name[len(spans.PREFIX):], []).append(
+                        (line.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+    assert sorted(found) == sorted(spans.SPANS)
+    assert all(len(v) == 1 for v in found.values()), found
+    assert len({v[0][0] for v in found.values()}) == 1     # one thread
+    for name, [(_, start, end, _)] in found.items():
+        parent = spans.SPANS[name][1]
+        if parent is not None:
+            _, p_start, p_end, _ = found[parent][0]
+            assert p_start <= start <= end <= p_end, name
+    args = {name: v[0][3] for name, v in found.items()}
+    assert args["store.search"] == {"queries": 40}
+    assert args["search.admit"] == {"rows": 40}
+    assert args["search.dispatch"] == {"blocks": 2, "q_block": 32}
+    assert args["search.route"] == {"width": 16}
+
+
+def test_every_span_is_in_the_table():
+    """Every ``span("<name>", ...)`` in the program names an entry of
+    ``spans.SPANS``, and every entry is used."""
+    import pathlib
+    import re
+
+    from repro.core import spans
+    src = pathlib.Path(spans.__file__).parents[1]
+    used = {m for f in src.rglob("*.py")
+            for m in re.findall(r'\bspan\(\s*"([^"]+)"', f.read_text())}
+    assert used == set(spans.SPANS)

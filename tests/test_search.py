@@ -385,3 +385,29 @@ def test_draw_entries_no_duplicates():
     # width clamps to n when beam > n
     small = np.asarray(_draw_entries(key, 8, 32, None))
     assert small.shape == (8,) and len(set(small.tolist())) == 8
+
+
+@pytest.mark.parametrize("precision", ["f32", "int8", "bf16"])
+def test_search_block_phases_are_named(built_graph, precision):
+    """Every phase of ``spans.SCOPES`` names ops of the compiled search
+    block (their ``op_name`` metadata), at each scoring precision, with
+    routed (per-query) entries."""
+    import importlib
+    import re
+
+    from repro.core import quantize
+    from repro.core.spans import SCOPES
+    gs = importlib.import_module("repro.core.graph_search")
+    x, _, idx = built_graph
+    qb, e = 8, 16
+    cfg = SearchConfig(beam=16, rounds=8, q_block=qb, precision=precision)
+    qstore = None if precision == "f32" else \
+        quantize.quantize_corpus(x, precision)
+    q = x[:qb] + 0.01
+    entry = jnp.tile(jnp.arange(e, dtype=jnp.int32), (qb, 1))
+    text = gs._search_block.lower(
+        x, jnp.sum(x * x, 1), idx, q, jnp.sum(q * q, 1), entry, None, None,
+        qstore, k_out=4, cfg=cfg).compile().as_text()
+    parts = {part for name in re.findall(r'op_name="([^"]+)"', text)
+             for part in name.split("/")[:-1]}
+    assert set(SCOPES) <= parts

@@ -162,3 +162,31 @@ def test_merge_rows(spec, no_cache):
 def test_compact(spec, no_cache):
     _compile(knn_compact_blocked, spec((N, K), f32), spec((N, K), i32),
              spec((N, K), jnp.bool_))
+
+
+def _scopes_named(compiled) -> set:
+    """The elements of the ``op_name`` paths of a compiled program, less
+    each path's last element (the op itself)."""
+    import re
+    return {part for name in re.findall(r'op_name="([^"]+)"',
+                                        compiled.as_text())
+            for part in name.split("/")[:-1]}
+
+
+def test_search_block_scopes(spec, no_cache):
+    """The fused search block at the served cell's shapes (a 60,000-row
+    store at its 65,536 capacity, dp 896, beam 256, 256-query blocks of
+    routed entries) keeps every phase name of ``spans.SCOPES`` in its
+    ops' ``op_name`` metadata after the TPU compiler's fusions: the chip
+    trace's ``tf_op`` carries these names."""
+    import importlib
+
+    from repro.core.spans import SCOPES
+    gs = importlib.import_module("repro.core.graph_search")
+    n, dp, qb, beam = 65_536, 896, 256, 256
+    cfg = gs.SearchConfig(beam=beam, rounds=256, backend="pallas")
+    compiled = gs._search_block.lower(
+        spec((n, dp), f32), spec((n,), f32), spec((n, K), i32),
+        spec((qb, dp), f32), spec((qb,), f32), spec((qb, beam), i32),
+        spec((n,), jnp.bool_), None, None, k_out=10, cfg=cfg).compile()
+    assert set(SCOPES) <= _scopes_named(compiled)
