@@ -7,7 +7,7 @@ from repro.core.faults import (
     InjectedFault,
     poison_batch,
 )
-from repro.core.graph_search import SearchConfig, graph_search
+from repro.core.graph_search import SearchConfig, SearchStats, graph_search
 from repro.core.heap import NeighborLists
 from repro.core.nn_descent import (
     DescentConfig,
@@ -57,6 +57,7 @@ __all__ = [
     "Router",
     "RouterConfig",
     "SearchConfig",
+    "SearchStats",
     "SnapshotError",
     "SnapshotWriter",
     "apply_permutation",

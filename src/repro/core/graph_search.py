@@ -18,10 +18,12 @@ Two implementations behind ``SearchConfig.backend``:
     (q_block, E·k) distance tile on the MXU (kernels/knn_search.py, norms
     hoisted once per batch), the ``knn_join_select`` partial top-C
     machinery reduces the tile under the pool's k-th-distance prefilter
-    (no per-round full argsorts), and the pool is maintained by the same
-    sort-free bounded merge as the build (``heap.merge_kernel`` /
-    ``ops.knn_merge``, dedup by id) with the NeighborLists ``new`` flag
-    reused as "not yet expanded". Sequential depth drops from ``rounds``
+    (no per-round full argsorts) to at most min(beam, E·k) sorted
+    candidates, and ``ops.knn_pool_insert`` inserts those into the sorted
+    pool (dedup by id), one candidate per step over the pool's lanes: the
+    round's merge costs O(beam·E·k), not a beam-long extraction over pool
+    plus candidates. It carries the NeighborLists ``new`` flag, reused as
+    "not yet expanded". Sequential depth drops from ``rounds``
     to ~``rounds/expand``, with a convergence early-out when no query in
     the block has an unexpanded pool entry left.
 
@@ -74,6 +76,7 @@ import dataclasses
 import functools
 import time
 import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -98,8 +101,9 @@ class SearchConfig:
     backend: str = "auto"   # auto | pallas | interpret = fused kernels;
                             # ref = the greedy one-node-per-round oracle
     select_c: int = 0       # candidate width handed to the pool merge
-                            # (0 = beam; the top-C select reduces the E*k
-                            # tile to this before the bounded merge)
+                            # (0 = the most the tile holds, min(beam,
+                            # E*k); the top-C select reduces the E*k tile
+                            # to this before the pool insert)
     precision: str = "f32"  # f32 | bf16 | int8 — candidate-SCORING dtype
                             # (kernels/l2_quant.py). Quantized modes run
                             # the whole beam traversal on the quantized
@@ -172,6 +176,16 @@ def q_block_bucket(nq: int, cfg: "SearchConfig") -> int:
     if cfg.fixed_block or nq <= 0:
         return max(1, cfg.q_block)
     return max(1, min(cfg.q_block, 1 << (nq - 1).bit_length()))
+
+
+class SearchStats(NamedTuple):
+    """Totals of one fused search over its query blocks
+    (``graph_search(..., stats=True)``): what the rounds' pool inserts
+    were offered and what they kept."""
+    blocks: int     # query blocks run
+    rounds: int     # rounds run, summed over the blocks
+    offered: int    # candidates that reached the insert past the dedup
+    inserted: int   # candidates in the pool after their round's insert
 
 
 @functools.partial(jax.jit, static_argnames=("hops", "capacity"))
@@ -325,9 +339,11 @@ def graph_search(
     router=None,                            # core/router.Router — routed seeds
     filter_ids: jax.Array | None = None,    # (n,) shared or (q, n) per-query
                                             # predicate mask (True = admitted)
+    stats: bool = False,
 ):
     """Returns (dist (q, k_out), idx (q, k_out)) ascending; empty slots
-    are (+inf/_BIG, -1).
+    are (+inf/_BIG, -1). With ``stats`` the fused path also returns a
+    ``SearchStats`` of its rounds, read back to the host.
 
     ``cfg`` wins over the legacy ``beam``/``rounds`` kwargs when given.
     ``entry`` may be (e,) shared across the batch or (q, e) per-query
@@ -364,6 +380,10 @@ def graph_search(
     """
     if cfg is None:
         cfg = SearchConfig(beam=beam, rounds=rounds)
+    if stats and cfg.backend == "ref":
+        raise ValueError("stats count the fused path's rounds; "
+                         "backend='ref' runs none")
+    no_stats = SearchStats(0, 0, 0, 0)
     x = x.astype(jnp.float32)
     queries = queries.astype(jnp.float32)
     if cfg.metric == "cosine":
@@ -397,8 +417,9 @@ def graph_search(
     if n == 0:
         # empty corpus (a store before its first insert): every query
         # gets the empty result, same contract as a fully-dead store
-        return (jnp.full((queries.shape[0], k_out), jnp.inf, jnp.float32),
-                jnp.full((queries.shape[0], k_out), -1, jnp.int32))
+        out = (jnp.full((queries.shape[0], k_out), jnp.inf, jnp.float32),
+               jnp.full((queries.shape[0], k_out), -1, jnp.int32))
+        return out + (no_stats,) if stats else out
     if x2 is None:
         x2 = jnp.sum(x * x, axis=1)
     if entry is None:
@@ -476,8 +497,9 @@ def graph_search(
     # bucket — unless cfg.fixed_block pins the full-block quantum.
     nq = queries.shape[0]
     if nq == 0:     # idle serving tick / empty insert batch
-        return (jnp.zeros((0, k_out), jnp.float32),
-                jnp.full((0, k_out), -1, jnp.int32))
+        out = (jnp.zeros((0, k_out), jnp.float32),
+               jnp.full((0, k_out), -1, jnp.int32))
+        return out + (no_stats,) if stats else out
     qb = q_block_bucket(nq, cfg)
     pad = (-nq) % qb
     with span("search.dispatch", blocks=(nq + pad) // qb, q_block=qb):
@@ -505,7 +527,7 @@ def graph_search(
                                       fixed_block=False)
         cut_cfg = None
         t0 = time.monotonic() if use_deadline else 0.0
-        outs_d, outs_i = [], []
+        outs_d, outs_i, counts = [], [], []
         for bi, s in enumerate(range(0, nq + pad, qb)):
             bcfg = run_cfg
             if use_deadline and bi > 0 \
@@ -514,18 +536,23 @@ def graph_search(
                     cut_cfg = dataclasses.replace(run_cfg, rounds=cfg.expand)
                 bcfg = cut_cfg
             ent_b = entry if entry.ndim == 1 else entry[s:s + qb]
-            od, oi = _search_block(
+            od, oi, *cnt = _search_block(
                 x, x2, graph_idx, qp[s:s + qb], q2[s:s + qb], ent_b, alive,
                 None if filt is None else filt[s:s + qb],
-                qstore, k_out=k_out, cfg=bcfg,
+                qstore, k_out=k_out, cfg=bcfg, stats=stats,
             )
+            counts += cnt
             if use_deadline:
                 od.block_until_ready()
             outs_d.append(od)
             outs_i.append(oi)
         out_d = outs_d[0] if len(outs_d) == 1 else jnp.concatenate(outs_d)
         out_i = outs_i[0] if len(outs_i) == 1 else jnp.concatenate(outs_i)
-        return _mask_bad_rows(out_d[:nq], out_i[:nq], bad_rows)
+        out = _mask_bad_rows(out_d[:nq], out_i[:nq], bad_rows)
+    if not stats:
+        return out
+    total = [int(t) for t in sum(counts)]
+    return out + (SearchStats(len(counts), *total),)
 
 
 # ---------------------------------------------------------------------------
@@ -533,7 +560,7 @@ def graph_search(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("k_out", "cfg"))
+@functools.partial(jax.jit, static_argnames=("k_out", "cfg", "stats"))
 def _search_block(
     x: jax.Array,          # (n, dp) f32 corpus
     x2: jax.Array,         # (n,) corpus squared norms (hoisted)
@@ -547,15 +574,19 @@ def _search_block(
     *,
     k_out: int,
     cfg: SearchConfig,
+    stats: bool = False,
 ):
     """One query block of the fused search (see module docstring).
     ``filt`` (per-query filtered search) masks candidates exactly like
-    ``alive`` does, gathered per query row."""
+    ``alive`` does, gathered per query row. Returns (dist, idx), and with
+    ``stats`` also the block's (3,) int32 totals: rounds run, candidates
+    offered to the pool insert past its dedup, candidates it kept."""
     n, k = graph_idx.shape
     qb = q.shape[0]
     beam = cfg.beam
     e = cfg.expand
-    c_sel = cfg.select_c or beam
+    # a select wider than the E*k tile only pads with (+inf, -1)
+    c_sel = cfg.select_c or min(beam, e * k)
     rows = jnp.arange(qb, dtype=jnp.int32)[:, None]
 
     # quantized scoring stage: the query block is quantized ONCE per block
@@ -635,7 +666,7 @@ def _search_block(
     )
 
     def round_fn(state):
-        pool_d, pool_i, pool_new, r = state
+        pool_d, pool_i, pool_new, r = state[:4]
         with jax.named_scope("select"):
             # top-E unexpanded pool slots per query (partial top-C select
             # — the same machinery as the build join, no full argsort)
@@ -694,27 +725,30 @@ def _search_block(
                     qq.data, qq.x2, rows_c, c2, cand, backend=cfg.backend,
                 )                                       # (qb, E*k)
         with jax.named_scope("merge"):
-            # pool-k-th prefilter + partial top-C, then the sort-free
-            # bounded merge (dedup by id; accepted slots come back
-            # unexpanded)
+            # pool-k-th prefilter + partial top-C, then the sorted
+            # insert (dedup by id; inserted slots come back unexpanded)
             cd, ci = ops.knn_join_select(
                 dd, cand, pool_d[:, -1], c=c_sel, backend=cfg.backend
             )
-            nl, _ = heap.merge_kernel(
-                NeighborLists(pool_d, pool_i, pool_new), cd, ci,
-                backend=cfg.backend,
+            pool_d, pool_i, pool_new, ins, off = ops.knn_pool_insert(
+                pool_d, pool_i, pool_new, cd, ci, backend=cfg.backend
             )
-        return nl.dist, nl.idx, nl.new, r + 1
+        out = (pool_d, pool_i, pool_new, r + 1)
+        if stats:
+            out += (state[4] + jnp.sum(off), state[5] + jnp.sum(ins))
+        return out
 
     def cond_fn(state):
-        pool_d, pool_i, pool_new, r = state
+        pool_d, pool_i, pool_new, r = state[:4]
         # early-out: every pool entry of every query already expanded
         with jax.named_scope("select"):
             return (r < cfg.n_rounds) & jnp.any(pool_new & (pool_i >= 0))
 
-    pool_d, pool_i, _, _ = jax.lax.while_loop(
+    # totals: the rounds run, and with stats the offered and inserted sums
+    zero = jnp.zeros((), jnp.int32)
+    pool_d, pool_i, _, *totals = jax.lax.while_loop(
         cond_fn, round_fn,
-        (pool.dist, pool.idx, pool.new, jnp.zeros((), jnp.int32)),
+        (pool.dist, pool.idx, pool.new, zero) + (zero, zero) * stats,
     )
     # exact re-rank of the final pool: difference-form fp32 distances on
     # the gathered rows (metric.exact_sq_l2). For quantized scoring
@@ -724,10 +758,11 @@ def _search_block(
     with jax.named_scope("rerank"):
         dex = metric_mod.exact_sq_l2(q, x[jnp.clip(pool_i, 0, n - 1)],
                                      pool_i)
-        return ops.knn_join_select(
+        out = ops.knn_join_select(
             dex, pool_i, jnp.full((qb,), jnp.inf, jnp.float32), c=k_out,
             backend=cfg.backend,
         )
+    return out + (jnp.stack(totals),) if stats else out
 
 
 # ---------------------------------------------------------------------------

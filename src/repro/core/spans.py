@@ -48,6 +48,6 @@ SCOPES = {
     "select": "the top-E unexpanded pool slots, and the loop's test",
     "gather": "adjacency rows, alive/filter masks, candidate rows, norms",
     "score": "the candidate distance tile (knn_search_dists, q8, bf16)",
-    "merge": "the pool-k-th prefilter select and the bounded merge",
+    "merge": "the pool-k-th prefilter select and the pool insert",
     "rerank": "the exact fp32 re-rank of the final pool and its select",
 }

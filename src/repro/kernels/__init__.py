@@ -7,7 +7,8 @@
 #                     prefilter/top-C selection, no global pair sort)
 #   knn_search      — query-time §3.3: blocked multi-expansion candidate
 #                     distance tile for the fused batched graph search
-#   knn_merge       — §2 bounded neighbor-list update
+#   knn_merge       — §2 bounded neighbor-list update, and the search's
+#                     per-round pool insert
 #   flash_attention — LM-stack attention hotspot (blocked online softmax)
 # ops.py = jit'd dispatch wrappers, ref.py = pure-jnp oracles.
 from repro.kernels import ops, ref
@@ -20,6 +21,7 @@ from repro.kernels.knn_merge import (
     knn_compact_rows_blocked,
     knn_merge_blocked,
     knn_merge_rows_blocked,
+    knn_pool_insert_blocked,
 )
 from repro.kernels.knn_search import knn_search_dists_blocked
 from repro.kernels.l2_blocked import pairwise_sq_l2_blocked
@@ -41,6 +43,7 @@ __all__ = [
     "knn_join_select_blocked",
     "knn_merge_blocked",
     "knn_merge_rows_blocked",
+    "knn_pool_insert_blocked",
     "knn_search_dists_bf16_blocked",
     "knn_search_dists_blocked",
     "knn_search_dists_q8_blocked",

@@ -13,6 +13,12 @@ VREGs — the analog of the paper keeping its 25 accumulators in registers.
 
 Outputs the merged sorted lists and the per-row accepted-candidate count
 (the convergence counter c in the NN-Descent stopping rule).
+
+The graph search's round merge runs a narrower form, ``knn_pool_insert``:
+its pool is the beam (256 wide) and a round offers only E·k candidates,
+so it inserts candidate by candidate into the sorted pool, each a
+vectorized step over the pool's lanes, instead of extracting beam minima
+from pool plus candidates.
 """
 from __future__ import annotations
 
@@ -21,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tiling
 
@@ -239,6 +246,141 @@ def knn_merge_blocked(
         name="knn_merge",
     )(cur_dist, cur_idx, cand_dist, cand_idx)
     return od[:n], oi[:n], upd[:n, 0]
+
+
+# ---------------------------------------------------------------------------
+# Search pool insert: the graph search's per-round merge, sized by the
+# round's candidates instead of the beam (core/graph_search.py).
+# ---------------------------------------------------------------------------
+
+
+def pool_insert_rows(n: int, p: int, w: int) -> int:
+    """Rows per block of the pool-insert kernel, from VMEM bytes: the
+    double-buffered pool rows in and out (dist, idx, flag), the candidate
+    rows and the two counts, plus about a dozen (p,) temporaries of the
+    insertion step. At most 64 rows, so that each (rows, p) array of the
+    step spans few vregs (not yet tuned on a chip)."""
+    io = 6 * tiling.row_bytes(p, jnp.float32) + 2 * tiling.row_bytes(
+        w, jnp.float32) + 2 * tiling.row_bytes(1, jnp.int32)
+    temps = 12 * tiling.row_bytes(p, jnp.float32) + 4 * tiling.row_bytes(
+        w, jnp.float32)
+    return tiling.rows_per_block(2 * io + temps, n, cap=64)
+
+
+def _pool_insert_kernel(nc_ref, pd_ref, pi_ref, pn_ref, cd_ref, ci_ref,
+                        od_ref, oi_ref, on_ref, ins_ref, off_ref):
+    """Insert a row block's candidates into its sorted pools, one
+    candidate column per step. A candidate goes after every pool entry
+    at a distance ``<=`` its own (the pool wins ties), the tail shifts one
+    lane (``pltpu.roll``) and the last lane falls off: the stable merge of
+    the pool and the candidates, cut to the pool's width. A candidate is
+    dropped when its id is in the pool as it came in, or repeats an
+    earlier candidate. ``nc_ref`` holds, per block, one past the last
+    column that holds a valid candidate in any of its rows."""
+    pool_i = pi_ref[...]                      # (TM, P) the pool as it came
+    cand_d = cd_ref[...]                      # (TM, W)
+    tm, p = pool_i.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tm, p), 1)
+    cand_lane = jax.lax.broadcasted_iota(jnp.int32, cand_d.shape, 1)
+
+    def column(a, j):
+        return jnp.sum(jnp.where(cand_lane == j, a, 0), axis=1,
+                       keepdims=True)
+
+    def step(j, carry):
+        d, i, flag, cand_i, offered = carry
+        cj = column(cand_i, j)                                  # (TM, 1)
+        in_pool = jnp.sum(jnp.where(pool_i == cj, 1, 0), axis=1,
+                          keepdims=True)
+        ok = (cj >= 0) & (in_pool == 0)
+        # later copies of this id are dropped where they stand
+        cand_i = jnp.where((cand_i == cj) & (cand_lane > j), -1, cand_i)
+        dj = jnp.where(ok, column(cand_d, j), _BIG)
+        # a prefix of the lanes; every lane when the candidate is dropped
+        keep = (d <= dj).astype(jnp.int32)
+        at = (keep == 0) & ((pltpu.roll(keep, 1, 1) == 1) | (lane == 0))
+        keep = keep == 1
+        d = jnp.where(keep, d, jnp.where(at, dj, pltpu.roll(d, 1, 1)))
+        i = jnp.where(keep, i, jnp.where(at, cj, pltpu.roll(i, 1, 1)))
+        # flag 2 marks an inserted entry: new, and counted at the end
+        flag = jnp.where(keep, flag,
+                         jnp.where(at, 2, pltpu.roll(flag, 1, 1)))
+        return d, i, flag, cand_i, offered + ok.astype(jnp.int32)
+
+    d0 = pd_ref[...]
+    init = (jnp.where(jnp.isinf(d0), _BIG, d0), pool_i, pn_ref[...],
+            ci_ref[...], jnp.zeros((tm, 1), jnp.int32))
+    d, i, flag, _, offered = jax.lax.fori_loop(
+        0, nc_ref[pl.program_id(0)], step, init)
+    hit = d < _BIG
+    od_ref[...] = jnp.where(hit, d, jnp.inf)
+    oi_ref[...] = jnp.where(hit, i, -1)
+    on_ref[...] = jnp.where(hit & (flag > 0), 1, 0)
+    ins_ref[...] = jnp.sum(jnp.where(flag == 2, 1, 0), axis=1, keepdims=True)
+    off_ref[...] = offered
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def knn_pool_insert_blocked(
+    pool_dist: jax.Array,  # (n, p) ascending, +inf = empty slot
+    pool_idx: jax.Array,   # (n, p) int32, -1 = empty
+    pool_new: jax.Array,   # (n, p) int32 flags, 0 on empty slots
+    cand_dist: jax.Array,  # (n, w) f32
+    cand_idx: jax.Array,   # (n, w) int32, -1 = invalid
+    *,
+    tm: int | None = None,    # rows per block (None: from VMEM bytes)
+    interpret: bool = False,
+):
+    """Merge candidates into sorted pools with their ``new`` flags: the
+    result equals the stable sort of each pool followed by its deduped
+    candidates, cut to ``p`` (``ref.knn_pool_insert``). Inserted entries
+    come back flagged 1; surviving pool entries keep their flag. The work
+    is O(p) per candidate column up to the block's last valid one, not
+    O(p + w) per pool slot: the search hands in ``w`` = E·k candidates,
+    sorted and packed to the front by ``knn_join_select``.
+
+    Returns (dist (n, p), idx (n, p), new (n, p) int32, inserted (n,),
+    offered (n,)): ``inserted`` counts the candidates that are in the
+    returned pool, ``offered`` those that survived the dedup."""
+    n, p = pool_dist.shape
+    w = cand_dist.shape[1]
+    tm = tm or pool_insert_rows(n, p, w)
+    npad = ((n + tm - 1) // tm) * tm
+    pad = npad - n
+    pool_dist = jnp.pad(pool_dist, ((0, pad), (0, 0)),
+                        constant_values=jnp.inf)
+    pool_idx = jnp.pad(pool_idx, ((0, pad), (0, 0)), constant_values=-1)
+    pool_new = jnp.pad(pool_new, ((0, pad), (0, 0)))
+    cand_dist = jnp.pad(cand_dist, ((0, pad), (0, 0)),
+                        constant_values=jnp.inf)
+    cand_idx = jnp.pad(cand_idx, ((0, pad), (0, 0)), constant_values=-1)
+    # per block: one past the last column with a valid candidate in it
+    col = jax.lax.broadcasted_iota(jnp.int32, cand_idx.shape, 1)
+    ncols = jnp.max(jnp.where(cand_idx >= 0, col + 1, 0).reshape(
+        npad // tm, tm * w), axis=1)
+
+    rows = lambda b, nc: (b, 0)
+    od, oi, on, ins, off = pl.pallas_call(
+        _pool_insert_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(npad // tm,),
+            in_specs=[pl.BlockSpec((tm, p), rows)] * 3
+            + [pl.BlockSpec((tm, w), rows)] * 2,
+            out_specs=[pl.BlockSpec((tm, p), rows)] * 3
+            + [pl.BlockSpec((tm, 1), rows)] * 2,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((npad, p), jnp.float32),
+            jax.ShapeDtypeStruct((npad, p), jnp.int32),
+            jax.ShapeDtypeStruct((npad, p), jnp.int32),
+            jax.ShapeDtypeStruct((npad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((npad, 1), jnp.int32),
+        ],
+        interpret=interpret,
+        name="knn_pool_insert",
+    )(ncols, pool_dist, pool_idx, pool_new, cand_dist, cand_idx)
+    return od[:n], oi[:n], on[:n], ins[:n, 0], off[:n, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,7 @@ shape/dtype sweeps.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
@@ -22,6 +23,7 @@ from repro.kernels.knn_merge import (
     knn_compact_rows_blocked,
     knn_merge_blocked,
     knn_merge_rows_blocked,
+    knn_pool_insert_blocked,
 )
 from repro.kernels.knn_search import knn_search_dists_blocked
 from repro.kernels.l2_blocked import pairwise_sq_l2_blocked
@@ -242,6 +244,29 @@ def knn_merge(
             cur_dist, cur_idx, cand_dist, cand_idx, interpret=True
         )
     return ref.knn_merge(cur_dist, cur_idx, cand_dist, cand_idx)
+
+
+def knn_pool_insert(
+    pool_dist: jax.Array,
+    pool_idx: jax.Array,
+    pool_new: jax.Array,
+    cand_dist: jax.Array,
+    cand_idx: jax.Array,
+    *,
+    backend: str = "auto",
+):
+    """The graph search's round merge: candidates into sorted pools with
+    their ``new`` flags (bool). Returns (dist, idx, new, inserted,
+    offered)."""
+    if backend == "auto":
+        backend = "pallas" if _on_tpu() else "ref"
+    if backend == "ref":
+        return ref.knn_pool_insert(pool_dist, pool_idx, pool_new, cand_dist,
+                                   cand_idx)
+    d, i, new, ins, off = knn_pool_insert_blocked(
+        pool_dist, pool_idx, pool_new.astype(jnp.int32), cand_dist,
+        cand_idx, interpret=backend == "interpret")
+    return d, i, new > 0, ins, off
 
 
 def knn_compact(

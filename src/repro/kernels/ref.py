@@ -264,6 +264,30 @@ def knn_merge(
     return new_dist, new_idx, updated
 
 
+def knn_pool_insert(
+    pool_dist: jax.Array,  # (n, p) ascending, +inf = empty
+    pool_idx: jax.Array,   # (n, p), -1 = empty
+    pool_new: jax.Array,   # (n, p) bool
+    cand_dist: jax.Array,  # (n, w)
+    cand_idx: jax.Array,   # (n, w)  (-1 = invalid slot)
+) -> tuple[jax.Array, ...]:
+    """The search's pool merge with its ``new`` flags: ``knn_merge``, then
+    surviving entries keep their flag and accepted ones are new. Returns
+    (dist, idx, new, inserted, offered), ``offered`` the per-row count of
+    candidates left after the dedup. Oracle for knn_pool_insert_blocked."""
+    dist, idx, inserted = knn_merge(pool_dist, pool_idx, cand_dist, cand_idx)
+    hit = idx[:, :, None] == pool_idx[:, None, :]
+    was_old = hit.any(-1)
+    kept_new = (hit & pool_new[:, None, :]).any(-1)
+    new = jnp.where(was_old, kept_new, True) & (idx >= 0)
+    dup = (cand_idx[:, :, None] == pool_idx[:, None, :]).any(-1)
+    w = cand_idx.shape[1]
+    earlier = jnp.tril(jnp.ones((w, w), dtype=bool), k=-1)[None]
+    dup |= ((cand_idx[:, :, None] == cand_idx[:, None, :]) & earlier).any(-1)
+    offered = jnp.sum((cand_idx >= 0) & ~dup, axis=1).astype(jnp.int32)
+    return dist, idx, new, inserted.astype(jnp.int32), offered
+
+
 def knn_compact(
     cur_dist: jax.Array,   # (n, k) ascending, +inf = empty
     cur_idx: jax.Array,    # (n, k), -1 = empty

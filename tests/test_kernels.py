@@ -5,9 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import heap
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.knn_merge import knn_merge_blocked
+from repro.kernels.knn_merge import knn_merge_blocked, knn_pool_insert_blocked
 from repro.kernels.l2_blocked import pairwise_sq_l2_blocked, vmem_bytes
 
 
@@ -100,6 +101,59 @@ def test_knn_merge_dedup():
     assert int(upd[0]) == 1                       # only 9 accepted
     assert 9 in np.asarray(i[0])
     assert sorted(np.asarray(i[0]).tolist()) == [5, 7, 9]
+
+
+def _pool_and_candidates(beam, width, rows=24, seed=0):
+    """Sorted pools and the search's select of a 3*width candidate tile,
+    with every case the insert must get right: tied distances (integer
+    draws), repeated candidate ids, candidates already in the pool, +inf
+    pool tails, a row with no valid candidate (row 0), a row whose
+    candidates fill its empty pool (row 1) and a full pool (row 2)."""
+    rng = np.random.RandomState(seed + beam + width)
+    ids = 4 * beam
+    pool_i = np.stack([rng.choice(ids, beam, replace=False)
+                       for _ in range(rows)]).astype(np.int32)
+    pool_d = np.sort(rng.randint(0, 30, (rows, beam)), 1).astype(np.float32)
+    fill = rng.randint(0, beam + 1, rows)
+    fill[1], fill[2] = 0, beam
+    for r in range(rows):
+        pool_d[r, fill[r]:], pool_i[r, fill[r]:] = np.inf, -1
+    pool_new = (rng.rand(rows, beam) < 0.5) & (pool_i >= 0)
+    tile_d = rng.randint(0, 30, (rows, 3 * width)).astype(np.float32)
+    tile_i = rng.randint(-1, ids, (rows, 3 * width)).astype(np.int32)
+    tile_i[:, 1::5] = tile_i[:, ::5][:, :tile_i[:, 1::5].shape[1]]
+    tile_i[2:, 2::4] = pool_i[2:, :tile_i[:, 2::4].shape[1]]
+    tile_i[0] = -1
+    tile_i[1] = np.arange(3 * width)            # distinct, all valid
+    cd, ci = ref.knn_join_select(jnp.asarray(tile_d), jnp.asarray(tile_i),
+                                 jnp.asarray(pool_d[:, -1]), width)
+    return (jnp.asarray(pool_d), jnp.asarray(pool_i), jnp.asarray(pool_new),
+            cd, ci)
+
+
+@pytest.mark.parametrize("beam,width", [(256, 80), (128, 80), (32, 32)])
+def test_pool_insert_matches_merge(beam, width):
+    """The search's pool insert equals today's round merge bit for bit:
+    the select's candidates through ref.knn_merge with the flags of
+    heap.merge_kernel."""
+    pool_d, pool_i, pool_new, cd, ci = _pool_and_candidates(beam, width)
+    want, upd = heap.merge_kernel(heap.NeighborLists(pool_d, pool_i,
+                                                     pool_new),
+                                  cd, ci, backend="ref")
+    d, i, new, ins, off = knn_pool_insert_blocked(
+        pool_d, pool_i, pool_new.astype(jnp.int32), cd, ci, tm=8,
+        interpret=True)
+    np.testing.assert_array_equal(d, want.dist)
+    np.testing.assert_array_equal(i, want.idx)
+    np.testing.assert_array_equal(new > 0, want.new)
+    np.testing.assert_array_equal(ins, upd)
+    np.testing.assert_array_equal(off, ref.knn_pool_insert(
+        pool_d, pool_i, pool_new, cd, ci)[4])
+    assert int(off[0]) == 0 and int(ins[0]) == 0          # nothing offered
+    assert int(ins[1]) == min(beam, width)                # fills the pool
+    assert bool(jnp.all(i[1] >= 0)) == (width >= beam)
+    assert int(jnp.sum(off)) < int(jnp.sum(ci >= 0))      # dedup dropped
+    assert bool(jnp.all(ins <= off)) and int(jnp.sum(ins)) > 0
 
 
 # ---------------------------------------------------------------------------

@@ -411,3 +411,48 @@ def test_search_block_phases_are_named(built_graph, precision):
     parts = {part for name in re.findall(r'op_name="([^"]+)"', text)
              for part in name.split("/")[:-1]}
     assert set(SCOPES) <= parts
+
+
+@pytest.mark.parametrize("select_c", [0, 64])
+@pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
+def test_search_block_insert_matches_ref(built_graph, precision, select_c):
+    """The search block's round merge through the pool-insert kernel
+    (interpret) returns what the ``ref`` oracles return, at a beam wider
+    than the E*k tile: select_c 0 narrows the select to E*k, select_c =
+    beam keeps it at the beam."""
+    import importlib
+
+    from repro.core import quantize
+    gs = importlib.import_module("repro.core.graph_search")
+    x, _, idx = built_graph
+    qb, beam = 16, 64
+    q = x[:qb] + 0.01
+    entry = jnp.tile(jnp.arange(24, dtype=jnp.int32), (qb, 1))
+    qstore = None if precision == "f32" else \
+        quantize.quantize_corpus(x, precision)
+    outs = [gs._search_block(
+        x, jnp.sum(x * x, 1), idx, q, jnp.sum(q * q, 1), entry, None, None,
+        qstore, k_out=K, cfg=SearchConfig(
+            beam=beam, rounds=16, q_block=qb, precision=precision,
+            select_c=select_c, backend=backend))
+        for backend in ("ref", "interpret")]
+    np.testing.assert_array_equal(outs[0][0], outs[1][0])
+    np.testing.assert_array_equal(outs[0][1], outs[1][1])
+
+
+def test_search_stats_counts(built_graph):
+    """``stats=True`` counts the rounds' pool inserts: what was kept was
+    offered, and no round offers more than its E*k tile per query."""
+    x, _, idx = built_graph
+    q = x[:40] + 0.02
+    cfg = SearchConfig(beam=32, rounds=16, q_block=16)
+    d, i, st = graph_search(x, idx, q, k_out=K, cfg=cfg, stats=True)
+    d0, i0 = graph_search(x, idx, q, k_out=K, cfg=cfg)
+    np.testing.assert_array_equal(i, i0)
+    np.testing.assert_array_equal(d, d0)
+    assert st.blocks == 3 and 0 < st.rounds <= 3 * cfg.n_rounds
+    assert 0 < st.inserted <= st.offered
+    assert st.offered <= st.rounds * cfg.expand * K * cfg.q_block
+    with pytest.raises(ValueError):
+        graph_search(x, idx, q, k_out=K, cfg=SearchConfig(backend="ref"),
+                     stats=True)

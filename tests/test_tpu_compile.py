@@ -31,6 +31,7 @@ from repro.kernels.knn_merge import (
     knn_compact_blocked,
     knn_merge_blocked,
     knn_merge_rows_blocked,
+    knn_pool_insert_blocked,
 )
 from repro.kernels.knn_search import knn_search_dists_blocked
 from repro.kernels.l2_blocked import pairwise_sq_l2_blocked
@@ -150,6 +151,15 @@ def test_merge(spec, no_cache, k, c):
     # pool merge at beam 128 and at chip_smoke.py's beam 256
     _compile(knn_merge_blocked, spec((N, k), f32), spec((N, k), i32),
              spec((N, c), f32), spec((N, c), i32))
+
+
+@pytest.mark.parametrize("beam,width", [(256, 80), (32, 32)])
+def test_pool_insert(spec, no_cache, beam, width):
+    # the search's round merge of 256-query blocks: beam 256 with E*k = 80
+    # candidates (the benchmark cell), and the default beam 32
+    _compile(knn_pool_insert_blocked, spec((256, beam), f32),
+             spec((256, beam), i32), spec((256, beam), i32),
+             spec((256, width), f32), spec((256, width), i32))
 
 
 def test_merge_rows(spec, no_cache):
